@@ -18,7 +18,8 @@ engine unpipelined (same staging, no lookahead), (c) the engine
 double-buffered, and (d) double-buffered with the auto-picked bucket family
 (granted-budget histogram) instead of the fixed 4.
 
-Distributed rows (``--distributed``, 8 virtual host devices): the same
+Distributed rows (``--distributed``, 8 devices: virtual host devices when
+the process is pinned to ``JAX_PLATFORMS=cpu``): the same
 comparison for the sharded scatter-gather backend over a *micro-batch*
 stream (a hot admission batcher) — monolithic dispatch (the PR 3
 behaviour: one whole-mesh program per arriving batch, step-granularity
@@ -37,14 +38,13 @@ multi-device matrix job.
 """
 from __future__ import annotations
 
-import os
 import sys
 import time
 
+from repro import runtime
+
 if "--distributed" in sys.argv:  # must precede the first jax import
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=8 "
-        + os.environ.get("XLA_FLAGS", ""))
+    runtime.virtual_cpu_devices(8)
 
 import jax
 import numpy as np
@@ -192,11 +192,10 @@ def compare_distributed(csv: common.Csv, x, q, gt, *, budget,
     from repro import compat
     from repro.distributed import sharded_search as ss
 
-    assert jax.device_count() >= 8, (
-        "run with --distributed (sets --xla_force_host_platform_device_count)")
     assert coalesce_lanes % batch == 0, (coalesce_lanes, batch)
     assert budget.center is not None, "rows need a pinned LID center"
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = compat.make_mesh((2, 4), ("data", "model"),
+                            devices=runtime.first_devices(8))
     build_cfg = build_cfg or build.BuildConfig(
         degree=16, beam_width=32, iters=1, batch=512, max_hops=64)
     arrays, per = ss.build_sharded_arrays(x, mesh, build_cfg=build_cfg,
@@ -335,8 +334,9 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="~60s CI smoke of the pipelined engine")
     ap.add_argument("--distributed", action="store_true",
-                    help="distributed rows on 8 virtual host devices "
-                         "(sets XLA_FLAGS; must be the process entry)")
+                    help="distributed rows on 8 devices (virtual host "
+                         "devices under JAX_PLATFORMS=cpu; must be the "
+                         "process entry)")
     ap.add_argument("--scale", default="small", choices=("small", "paper"))
     args = ap.parse_args()
     if args.smoke and args.distributed:

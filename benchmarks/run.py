@@ -20,6 +20,9 @@ def main() -> None:
                     help="comma-separated benchmark names")
     args = ap.parse_args()
 
+    from repro import runtime
+
+    runtime.use_compile_cache()
     from benchmarks import (
         adaptive_beam,
         build_time,

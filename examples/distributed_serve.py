@@ -14,15 +14,15 @@ single-program step (asserted below). The example finishes with a per-shard
 (lam, l_min) calibration pass — each shard's sub-graph has its own geometry,
 so one global law under- or over-budgets some shards.
 
-    PYTHONPATH=src python examples/distributed_serve.py
-(sets XLA_FLAGS itself; run as a script, not inside another jax process)
-"""
-import os
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_serve.py
 
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 "
-    + os.environ.get("XLA_FLAGS", "")
-)
+Pinned to the CPU it asks for 8 virtual host devices (run it as a script,
+not inside another jax process); on a host with 8 chips the mesh is built
+from the chips.
+"""
+from repro import runtime
+
+runtime.virtual_cpu_devices(8)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -36,7 +36,8 @@ from repro.distributed import sharded_search as ss  # noqa: E402
 
 
 def main():
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = compat.make_mesh((2, 4), ("data", "model"),
+                            devices=runtime.first_devices(8))
     n_shards = mesh.devices.size
     x, queries = make_dataset("tiny-mixture", seed=0)
     queries = np.asarray(queries[:64])

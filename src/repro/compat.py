@@ -1,50 +1,22 @@
-"""Version compatibility for the jax API surface this repo targets.
+"""The two jax API spellings the mesh code shares (jax 0.9).
 
-The serving/distributed code is written against the current jax API
-(``jax.shard_map``, ``jax.sharding.AxisType``); older runtimes (<= 0.4.x)
-ship the same functionality under ``jax.experimental.shard_map`` with the
-``check_rep`` spelling and have no mesh axis types. Routing every use
-through this module keeps the rest of the codebase on the modern spelling.
+``make_mesh`` pins Auto axis types (the sharded search relies on GSPMD
+propagation, not explicit-sharding types); ``shard_map`` turns off the
+varying-manual-axes check, which the shard-local walk programs do not
+satisfy.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: axis types are part of the public sharding API.
-    from jax.sharding import AxisType as _AxisType
-except ImportError:
-    _AxisType = None
+from jax.sharding import AxisType
 
 
-def make_mesh(shape, names):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, names,
-                             axis_types=(_AxisType.Auto,) * len(names))
-    return jax.make_mesh(shape, names)
+def make_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict.
-
-    Older jax returns a one-element list of per-computation dicts; newer jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -10,9 +10,10 @@ alpha(u); newly created edges are mirrored (reverse-edge insertion with
 re-pruning of overfull destinations), which is what makes the graph navigable
 from the medoid.
 
-The loop is host-orchestrated over jitted batch steps (search + prune are
-fixed-shape jitted kernels); batch size trades host round-trips against the
-(B, C, D) candidate-gather footprint.
+The loop is host-orchestrated over one jitted program per batch step
+(search + prune + mirrored-edge insertion, fixed shapes, no host sync);
+batch size trades launches against the (B, C, D) candidate-gather
+footprint.
 
 ``build_vamana`` (the DiskANN baseline) is the same procedure with the
 constant-alpha mapping — the framework's way of isolating the paper's single
@@ -21,6 +22,7 @@ moving part.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
@@ -53,6 +55,7 @@ class BuildConfig:
     seed: int = 0
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def random_graph(n: int, degree: int, key: Array) -> Array:
     """R-regular random initial graph (Algorithm 1's RandomGraph).
 
@@ -64,16 +67,11 @@ def random_graph(n: int, degree: int, key: Array) -> Array:
     def row(k, u):
         ids = jax.random.randint(k, (degree,), 0, n, dtype=jnp.int32)
         ids = jnp.where(ids == u, (ids + 1) % n, ids)  # no self-loops
-        # Mark duplicate ids INVALID (order-preserving dedup).
-        srt = jnp.sort(ids)
-        dup_sorted = jnp.concatenate(
-            [jnp.zeros((1,), bool), srt[1:] == srt[:-1]]
-        )
-        # An id is a duplicate occurrence if an earlier slot holds the same id.
+        # Mark duplicate ids INVALID (order-preserving dedup): an id is a
+        # duplicate occurrence if an earlier slot holds the same id.
         earlier_same = (ids[None, :] == ids[:, None]) & (
             jnp.arange(degree)[None, :] < jnp.arange(degree)[:, None]
         )
-        del dup_sorted
         return jnp.where(earlier_same.any(axis=1), INVALID, ids)
 
     return jax.vmap(row)(keys, jnp.arange(n, dtype=jnp.int32))
@@ -87,7 +85,7 @@ def _rewire_batch(
     node_ids: Array,
     cfg: BuildConfig,
 ) -> tuple[Array, Array]:
-    """One jitted refinement step for a batch of nodes.
+    """The re-wiring half of a refinement step for a batch of nodes.
 
     Greedy-search each node's own vector on the current graph, pool the beam
     with the node's current neighbours, robust-prune with alpha(u).
@@ -156,6 +154,64 @@ def _insert_reverse(
     return adj.at[dest].set(rows, mode="drop")
 
 
+def _reverse_pairs_device(
+    node_ids: Array, new_rows: Array, cap: int, n: int
+) -> tuple[Array, Array, Array]:
+    """On-device twin of :func:`_reverse_pairs` (same destinations in the
+    same ascending order, same candidate rows), at the fixed length
+    E = B*R of the step's edge list so it can run inside the step program.
+
+    Returns (dest (E,), cand (E, cap), count): the first ``count`` entries
+    are the real destinations; the rest are filler.
+    """
+    e = new_rows.size
+    us = jnp.repeat(node_ids, new_rows.shape[1])
+    vs = new_rows.reshape(-1)
+    key = jnp.where(vs >= 0, vs, n)          # dropped edges sort last
+    order = jnp.argsort(key, stable=True)
+    us, vs = us[order], key[order]
+    pos = jnp.arange(e, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), vs[1:] != vs[:-1]])
+    group = jnp.cumsum(first, dtype=jnp.int32) - 1
+    rank = pos - jax.lax.cummax(jnp.where(first, pos, 0))
+    live = vs < n
+    count = jnp.sum(first & live, dtype=jnp.int32)
+    dest = jnp.zeros((e,), jnp.int32).at[
+        jnp.where(first & live, group, e)].set(vs, mode="drop")
+    keep = live & (rank < cap)
+    cand = jnp.full((e, cap), INVALID, jnp.int32).at[
+        jnp.where(keep, group, e), jnp.minimum(rank, cap - 1)
+    ].set(us, mode="drop")
+    return dest, cand, count
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _refine_step(
+    x: Array, adj: Array, alpha: Array, entry: Array, node_ids: Array,
+    cfg: BuildConfig,
+) -> Array:
+    """One refinement step as one program: re-wire the batch
+    (:func:`_rewire_batch`), then mirror its new edges chunk by chunk
+    (:func:`_insert_reverse`, ``cfg.batch`` destinations per chunk, a short
+    last chunk padded with its first destination and no candidates) — the
+    host never waits on the step, so the build runs at device speed."""
+    new_rows, _ = _rewire_batch(x, adj, alpha, entry, node_ids, cfg)
+    adj = adj.at[node_ids].set(new_rows)
+    dest, cand, count = _reverse_pairs_device(
+        node_ids, new_rows, cfg.reverse_cap, adj.shape[0])
+    b = cfg.batch
+    chunks = (count + b - 1) // b
+    last = dest[jnp.maximum(chunks - 1, 0) * b]
+    dest = jnp.where(jnp.arange(dest.shape[0]) < count, dest, last)
+
+    def insert_chunk(c, adj):
+        return _insert_reverse(
+            x, adj, alpha, jax.lax.dynamic_slice_in_dim(dest, c * b, b),
+            jax.lax.dynamic_slice_in_dim(cand, c * b, b), cfg)
+
+    return jax.lax.fori_loop(0, chunks, insert_chunk, adj)
+
+
 def build_with_alpha(
     x: Array,
     alpha: Array,
@@ -177,25 +233,9 @@ def build_with_alpha(
             ids_np = perm[start : start + cfg.batch]
             if ids_np.size < cfg.batch:  # keep jit shapes fixed: wrap-around pad
                 ids_np = np.concatenate([ids_np, perm[: cfg.batch - ids_np.size]])
-            node_ids = jnp.asarray(ids_np)
-            new_rows, _ = _rewire_batch(x, adj, alpha, entry, node_ids, cfg)
-            adj = adj.at[node_ids].set(new_rows)
-            dest, cand = _reverse_pairs(
-                ids_np, np.asarray(new_rows), cfg.reverse_cap
-            )
-            for ds in range(0, dest.shape[0], cfg.batch):
-                dslice = dest[ds : ds + cfg.batch]
-                cslice = cand[ds : ds + cfg.batch]
-                if dslice.size < cfg.batch:
-                    pad = cfg.batch - dslice.size
-                    dslice = np.concatenate([dslice, dslice[:1].repeat(pad)])
-                    cslice = np.concatenate(
-                        [cslice, np.full((pad, cfg.reverse_cap), INVALID, np.int32)]
-                    )
-                adj = _insert_reverse(
-                    x, adj, alpha, jnp.asarray(dslice), jnp.asarray(cslice), cfg
-                )
+            adj = _refine_step(x, adj, alpha, entry, jnp.asarray(ids_np), cfg)
         if progress:
+            adj.block_until_ready()
             progress(f"refinement round {it + 1}/{cfg.iters} done")
     return adj
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
+HIGHEST = jax.lax.Precision.HIGHEST   # f32 matmuls on the MXU, not bf16
 
 # Metric names accepted across the framework.
 L2 = "l2"
@@ -33,18 +34,20 @@ def squared_l2(q: Array, x: Array) -> Array:
       x: (N, D) base points.
     Returns:
       (Q, N) squared distances, computed via the expansion
-      ``|q|^2 - 2 q.x + |x|^2`` so the contraction hits the MXU.
+      ``|q|^2 - 2 q.x + |x|^2`` so the contraction hits the MXU — at full
+      f32 precision: the TPU's default one-pass bf16 product loses the
+      small gaps between large-norm neighbours the expansion subtracts.
     """
     qn = jnp.sum(q * q, axis=-1, keepdims=True)  # (Q, 1)
     xn = jnp.sum(x * x, axis=-1)  # (N,)
-    dot = q @ x.T  # (Q, N)
+    dot = jnp.matmul(q, x.T, precision=HIGHEST)  # (Q, N)
     d2 = qn - 2.0 * dot + xn[None, :]
     return jnp.maximum(d2, 0.0)
 
 
 def neg_inner_product(q: Array, x: Array) -> Array:
     """Negated inner product as a distance (smaller = more similar)."""
-    return -(q @ x.T)
+    return -jnp.matmul(q, x.T, precision=HIGHEST)
 
 
 def pairwise(q: Array, x: Array, metric: str = L2) -> Array:
@@ -94,11 +97,10 @@ def brute_force_topk(
         d = jnp.where(valid[None, :], d, jnp.inf)
         cat_d = jnp.concatenate([best_d, d], axis=1)
         cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids, (nq, chunk))], axis=1)
-        order = jnp.argsort(cat_d, axis=1)[:, :k]
-        return (
-            jnp.take_along_axis(cat_d, order, axis=1),
-            jnp.take_along_axis(cat_i, order, axis=1),
-        ), None
+        # top_k breaks ties lowest-index-first, exactly as a stable argsort
+        # [:k] does, without sorting the whole (Q, k + chunk) block.
+        neg_d, order = jax.lax.top_k(-cat_d, k)
+        return (-neg_d, jnp.take_along_axis(cat_i, order, axis=1)), None
 
     (best_d, best_i), _ = jax.lax.scan(
         body, (init_d, init_i), jnp.arange(n_chunks, dtype=jnp.int32)

@@ -126,7 +126,9 @@ def robust_prune_batch(
 
     # Pairwise candidate distances for occlusion tests.
     sq = jnp.sum(cvecs * cvecs, axis=-1)  # (B, C)
-    pd2 = sq[:, :, None] - 2.0 * jnp.einsum("bcd,bed->bce", cvecs, cvecs) + sq[:, None, :]
+    dots = jnp.einsum("bcd,bed->bce", cvecs, cvecs,
+                      precision=jax.lax.Precision.HIGHEST)
+    pd2 = sq[:, :, None] - 2.0 * dots + sq[:, None, :]
     pd2 = jnp.maximum(pd2, 0.0)
 
     ids = jnp.where(bad, INVALID, cand_ids)

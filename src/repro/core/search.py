@@ -27,13 +27,14 @@ and :class:`PallasBeamStep` swaps the whole batched hop for one fused
 ``repro.kernels.beam_step`` launch per hop (beam state in VMEM, one kernel
 instead of a chain of HLOs). Every walk entry point — fixed-beam, probe and
 continue — takes ``step_kernel=`` (``None``/"reference" | "pallas" |
-"auto"), threaded from the serving engines as a static jit key.
-"reference" is the default everywhere (bit-stable, no dispatch-policy
-dependence); "pallas" forces the fused kernel (compiled on TPU, interpret
-elsewhere — bit-identical to the reference, see
-:mod:`repro.kernels.beam_step`); "auto" consults the
-:func:`repro.kernels.ops.resolve_impl` policy and falls back to the
-reference off-TPU unless interpret mode is requested.
+"auto"), threaded from the serving engines as a static jit key.  The
+serving backends default to "auto", which consults the
+:func:`repro.kernels.ops.resolve_impl` policy: the fused kernel on a TPU
+(or under ``REPRO_PALLAS_INTERPRET=1``), the reference hop otherwise.
+"pallas" forces the fused kernel (compiled on TPU, interpret elsewhere —
+bit-identical to the reference there, see :mod:`repro.kernels.beam_step`);
+"reference" (and ``None``, the default of the core entry points) is the
+reference hop on every platform.
 """
 from __future__ import annotations
 
@@ -294,8 +295,8 @@ class BeamStepKernel:
 
 
 class PallasBeamStep(BeamStepKernel):
-    """Fused-hop execution: one ``repro.kernels.ops.beam_step`` launch per
-    hop of the whole batch, beam state resident in VMEM.
+    """Fused-hop execution: one fused ``repro.kernels.beam_step`` launch per
+    hop of the whole batch (``repro.kernels.ops.beam_walk``).
 
     The per-lane ``step`` body is inherited unchanged (it *is* the hop's
     semantics); ``run_batch`` replaces the vmap-of-while shape with one
@@ -306,12 +307,11 @@ class PallasBeamStep(BeamStepKernel):
 
     The fused kernel sees through the two standard evaluators via their
     ``kind``/``table`` tags (:func:`_exact_eval`, :func:`_pq_eval`, and the
-    distributed shard evaluator); an untagged custom evaluator falls back to
-    the reference execution shape.
+    distributed shard evaluator); an untagged custom evaluator is an error,
+    never a silent switch to the reference hop.
     """
 
     name = "pallas"
-    request = "pallas"   # ops-layer dispatch: interpret off-TPU, never oracle
 
     def run_batch(self, states, ctxs: Array, adj: Array,
                   eval_dists: DistEval, beam_width: int, hop_limits: Array,
@@ -319,28 +319,17 @@ class PallasBeamStep(BeamStepKernel):
         kind = getattr(eval_dists, "kind", None)
         table = getattr(eval_dists, "table", None)
         if kind not in ("exact", "pq") or table is None:
-            return super().run_batch(states, ctxs, adj, eval_dists,
-                                     beam_width, hop_limits, budgets)
+            raise ValueError(
+                "the fused beam step needs a kind/table-tagged evaluator "
+                "(_exact_eval, _pq_eval or the shard evaluator); use "
+                "step_kernel='reference' for a custom one")
         from repro.kernels import ops
 
         q = hop_limits.shape[0]
         b = (jnp.full((q,), beam_width, jnp.int32) if budgets is None
              else jnp.broadcast_to(budgets, (q,)).astype(jnp.int32))
         hl = jnp.broadcast_to(hop_limits, (q,)).astype(jnp.int32)
-
-        def cond(st):
-            beam_ids, _, beam_exp, _, hops, _ = st
-            in_b = jax.lax.broadcasted_iota(
-                jnp.int32, beam_ids.shape, 1) < b[:, None]
-            frontier = jnp.any(
-                (~beam_exp) & (beam_ids != INVALID) & in_b, axis=1)
-            return jnp.any((hops < hl) & frontier)
-
-        def body(st):
-            return ops.beam_step(st, ctxs, adj, table, b, hl, kind=kind,
-                                 request=self.request)
-
-        return jax.lax.while_loop(cond, body, states)
+        return ops.beam_walk(states, ctxs, adj, table, b, hl, kind=kind)
 
 
 REFERENCE_STEP = BeamStepKernel()
@@ -353,7 +342,8 @@ def resolve_step_kernel(
     """Resolve a ``step_kernel=`` knob to a kernel object.
 
     ``None``/"reference" -> the reference hop; "pallas" -> the fused kernel
-    (compiled on TPU, interpret-mode elsewhere — bit-identical either way);
+    (compiled on TPU; interpret-mode elsewhere, bit-identical to the
+    reference there);
     "auto" -> whatever :func:`repro.kernels.ops.resolve_impl` picks for this
     process (the fused kernel on TPU or under ``REPRO_PALLAS_INTERPRET=1``,
     the reference otherwise).  Kernel instances pass through, so tests can

@@ -58,6 +58,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
@@ -626,12 +627,18 @@ def build_sharded_arrays(
     :func:`make_distributed_search` requires. ``x`` is truncated to a
     multiple of the shard count. Returns (arrays dict, rows_per_shard).
 
+    Each shard's sub-graph is built on the mesh device that will own it,
+    all shards concurrently (one host thread per device; the builds are
+    independent, so the result does not depend on the interleaving).
+
     Example/benchmark/test scale: production builds each shard's sub-graph
     on the host that owns it and ships the serializer's per-shard files;
     this helper exists so every in-process harness (examples, workers,
     benchmarks, the serve launcher's ``--distributed`` mode) shards one
     collection the same way.
     """
+    import concurrent.futures
+
     from repro.core import build as build_mod
     from repro.pq import pq_encode, train_pq
 
@@ -640,12 +647,16 @@ def build_sharded_arrays(
     n = (x.shape[0] // n_shards) * n_shards
     x = x[:n]
     per = n // n_shards
-    adj = jnp.concatenate([
-        build_mod.build_with_alpha(
-            x[s * per:(s + 1) * per],
-            jnp.full((per,), alpha, jnp.float32), build_cfg)
-        for s in range(n_shards)
-    ])
+
+    def build_shard(s, device):
+        xs = jax.device_put(x[s * per:(s + 1) * per], device)
+        with jax.default_device(device):
+            return np.asarray(build_mod.build_with_alpha(
+                xs, jnp.full((per,), alpha, jnp.float32), build_cfg))
+
+    with concurrent.futures.ThreadPoolExecutor(n_shards) as pool:
+        adj = np.concatenate(list(pool.map(
+            build_shard, range(n_shards), mesh.devices.flat)))
     book = train_pq(x, m=m_pq, iters=pq_iters, seed=seed)
     axes = _shard_axes(mesh)
     row = NamedSharding(mesh, P(axes, None))

@@ -11,11 +11,10 @@ Dispatch policy (one shared :func:`resolve_impl`, used by every wrapper):
    (fast on CPU, same semantics).
 
 Libraries call these wrappers only — never pallas_call directly — so the
-integration point is uniform across hardware.  :func:`beam_step` additionally
-takes a ``request`` from the step-kernel layer: ``request="pallas"`` means
-the caller explicitly asked for the fused kernel, so off-TPU it upgrades the
-oracle fallback to interpret mode (bit-identical to the compiled kernel)
-instead of silently handing back the reference walk.
+integration point is uniform across hardware.  :func:`beam_walk` is the one
+exception to rule 3: the step-kernel layer calls it only when the fused
+kernel was asked for, so off-TPU it runs interpret mode (bit-identical to
+the compiled kernel) instead of silently handing back the reference walk.
 """
 from __future__ import annotations
 
@@ -93,20 +92,13 @@ def decode_attention(q: Array, k: Array, v: Array, kv_len: Array) -> Array:
     return _da.decode_attention(q, k, v, kv_len, interpret=impl == "interpret")
 
 
-def beam_step(state, ctxs: Array, adj: Array, table: Array, budgets: Array,
-              hop_limits: Array, *, kind: str, request: str = "auto"):
-    """One fused hop of the batched beam walk; see
-    :mod:`repro.kernels.beam_step` for the state layout.
+def beam_walk(states, ctxs: Array, adj: Array, table: Array, budgets: Array,
+              hop_limits: Array, *, kind: str):
+    """Run a batch of walk lanes to convergence, one fused hop per launch;
+    see :func:`repro.kernels.beam_step.beam_walk`.
 
-    ``request="pallas"`` (the ``step_kernel="pallas"`` knob) never falls back
-    to the oracle: off-TPU the kernel body runs in interpret mode instead, so
-    "pallas" always means the fused kernel's own arithmetic.
+    Always the fused kernel: compiled on TPU, interpret mode elsewhere (the
+    caller asked for the kernel by name, so the oracle is never substituted).
     """
-    impl = resolve_impl()
-    if impl == "ref" and request == "pallas":
-        impl = "interpret"
-    if impl == "ref":
-        return _ref.beam_step_ref(
-            state, ctxs, adj, table, budgets, hop_limits, kind=kind)
-    return _beam.beam_step(state, ctxs, adj, table, budgets, hop_limits,
-                           kind=kind, interpret=impl == "interpret")
+    return _beam.beam_walk(states, ctxs, adj, table, budgets, hop_limits,
+                           kind=kind, interpret=resolve_impl() != "pallas")

@@ -1,10 +1,14 @@
 import os
+# A CPU-only rehearsal over 512 fake devices: pin this process and every
+# per-cell child (they inherit the environment) to the CPU backend so the
+# sweep never takes a TPU, then ask for the fake devices.  Both MUST run
+# before any jax import (platform and device count lock at first backend
+# init); everything below may import jax freely.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
-# The two lines above MUST run before any jax import (device count locks at
-# first backend init); everything below may import jax freely.
 
 """Multi-pod dry-run driver (deliverable e).
 
@@ -25,7 +29,7 @@ Usage:
   python -m repro.launch.dryrun --all            # every cell, subprocess each
   python -m repro.launch.dryrun --list
 
-(note: no ``from __future__`` here — the XLA_FLAGS lines must stay first.)
+(note: no ``from __future__`` here — the environment lines must stay first.)
 """
 import argparse
 import json
@@ -44,9 +48,7 @@ def _measure(cell):
     t0 = time.time()
     compiled = cell.lower().compile()
     t_compile = time.time() - t0
-    from repro import compat
-
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = hlo_analysis.collective_bytes(compiled.as_text())
     metrics = {
         "flops": float(cost.get("flops", 0.0)),

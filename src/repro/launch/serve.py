@@ -60,23 +60,31 @@ and the report counts out-of-filter results (must be 0). Single-host only:
 the distributed backend has no global-id view for the bitset (see ROADMAP
 carry-overs).
 
-``--distributed N`` shards the dataset over N virtual host devices (one
-locally built sub-graph per shard) and serves scatter-gather through a
+``--distributed N`` shards the dataset over the first N devices (the chips
+of a TPU host; N virtual host devices when the process is pinned to
+``JAX_PLATFORMS=cpu``) with one locally built sub-graph per shard, and serves scatter-gather through a
 ``DistributedBackend``. With ``--adaptive`` the distributed step runs
 *staged* at full engine parity — probe checkpointed at the horizon, host
 bucket scheduling between mesh programs, per-bucket continues into the
 hedged merge — so ``--pipeline`` overlaps batch i+1's distributed probe
 with batch i's bucketing and continues. ``--calibrate --per-shard`` fits
 one (lam, l_min) law per shard on shard-local held-out queries and serves
-the laws as runtime arrays. Sets XLA_FLAGS itself, so run it as the
-process entry point (the flag must precede the first jax import).
+the laws as runtime arrays. On the CPU it sets XLA_FLAGS itself, so run it
+as the process entry point (the flag must precede the first jax import).
+
+The first ``[serve]`` line names the platform, device kind and device count.
+The run exits non-zero when any front-door request ends in ``"error"`` (or
+a deadline hedge raised) and when a filtered run returns an out-of-filter
+id (:func:`run_failures`).
 """
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
+from typing import Mapping
+
+from repro import runtime
 
 
 def _distributed_engine(args, x, queries, budget_cfg, num_buckets):
@@ -90,7 +98,8 @@ def _distributed_engine(args, x, queries, budget_cfg, num_buckets):
     from repro.core import build, calibrate
     from repro.distributed import sharded_search as ss
 
-    mesh = compat.make_mesh((args.distributed,), ("data",))
+    mesh = compat.make_mesh((args.distributed,), ("data",),
+                            devices=runtime.first_devices(args.distributed))
     n_shards = mesh.devices.size
     t0 = time.time()
     arrays, per = ss.build_sharded_arrays(
@@ -147,11 +156,32 @@ def _report_disk_tier(backend, model) -> None:
               f"promotion_io_blocks={st['promotion_io_blocks']}")
 
 
+def run_failures(door_stats: Mapping[str, int] | None = None,
+                 out_of_filter: int = 0) -> list[str]:
+    """Why a serving run must exit non-zero (empty when it may exit 0).
+
+    ``door_stats`` is :meth:`repro.serving.server.FrontDoor.stats`: any
+    request that ended in ``"error"`` or any deadline hedge that raised is
+    a failure — shed and timeout are load outcomes, not faults.
+    ``out_of_filter`` counts filtered-run results outside their filter."""
+    reasons = []
+    if door_stats is not None:
+        if door_stats.get("error", 0):
+            reasons.append(f"{door_stats['error']} front-door request(s) "
+                           "ended in status 'error'")
+        if door_stats.get("partial_errors", 0):
+            reasons.append(f"{door_stats['partial_errors']} deadline "
+                           "hedge(s) raised")
+    if out_of_filter:
+        reasons.append(f"{out_of_filter} result(s) outside their filter")
+    return reasons
+
+
 def _serve_front_door(args, backend, index, queries, gt_i,
-                      budget_cfg, num_buckets) -> None:
+                      budget_cfg, num_buckets) -> dict:
     """Closed-loop front-door serving on the wall clock: one budget-law
     engine per QoS class over the shared backend, arrival pacing at --qps,
-    per-class SLO report."""
+    per-class SLO report.  Returns the front door's stats."""
     import dataclasses
 
     import numpy as np
@@ -252,7 +282,9 @@ def _serve_front_door(args, backend, index, queries, gt_i,
     print(f"[serve] admission: submitted={st['submitted']} "
           f"admitted={st['admitted']} shed={st['shed']} "
           f"dispatches={st['dispatches']} "
-          f"max_open={st['max_open_lanes']}/{door.max_queue}")
+          f"max_open={st['max_open_lanes']}/{door.max_queue} "
+          f"errors={st['error']} partial_errors={st['partial_errors']}")
+    return st
 
 
 def buckets_arg(value: str):
@@ -410,10 +442,9 @@ def main() -> None:
             ap.error("--distributed must set XLA_FLAGS before jax is "
                      "imported; run repro.launch.serve as the process "
                      "entry point")
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.distributed} "
-            + os.environ.get("XLA_FLAGS", ""))
+        runtime.virtual_cpu_devices(args.distributed)
 
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -423,6 +454,11 @@ def main() -> None:
     from repro.index import build_tiered_index, load_index, save_index
     from repro.index.disk import DiskTierModel
 
+    runtime.use_compile_cache()
+    dev = jax.devices()
+    print(f"[serve] platform={dev[0].platform} "
+          f"device_kind={dev[0].device_kind} devices={len(dev)} "
+          f"kernel={args.kernel}")
     x, queries = make_dataset(args.dataset, seed=0)
     import pathlib
 
@@ -477,10 +513,11 @@ def main() -> None:
         backend = serving.TieredBackend(index, slow_tier=slow_tier,
                                         step_kernel=args.kernel)
         if args.serve:
-            _serve_front_door(args, backend, index, queries, gt_i,
-                              budget_cfg, num_buckets)
+            st = _serve_front_door(args, backend, index, queries, gt_i,
+                                   budget_cfg, num_buckets)
             if args.disk:
                 _report_disk_tier(backend, model)
+            _exit_on_failures(run_failures(st))
             return
         if args.adaptive:
             engine = serving.SearchEngine(backend, budget_cfg, k=args.k,
@@ -592,6 +629,12 @@ def main() -> None:
               f"(in-graph, must be 0)")
     if not args.distributed and args.disk:
         _report_disk_tier(backend, model)
+    _exit_on_failures(run_failures(out_of_filter=out_of_filter))
+
+
+def _exit_on_failures(reasons: list[str]) -> None:
+    if reasons:
+        raise SystemExit("[serve] FAILED: " + "; ".join(reasons))
 
 
 if __name__ == "__main__":

@@ -24,7 +24,8 @@ def _encode_chunked(x: Array, centroids: Array, chunk: int = 16384) -> Array:
         def per_sub(sub, cb):
             d2 = (
                 jnp.sum(sub * sub, axis=1, keepdims=True)
-                - 2.0 * sub @ cb.T
+                - 2.0 * jnp.matmul(sub, cb.T,
+                                   precision=jax.lax.Precision.HIGHEST)
                 + jnp.sum(cb * cb, axis=1)[None, :]
             )
             return jnp.argmin(d2, axis=1).astype(jnp.uint8)

@@ -182,7 +182,7 @@ class ExactBackend(_StagedRerankMixin):
     staged = True
 
     def __init__(self, x: Array, adj: Array, entry: Array,
-                 step_kernel: str | None = None):
+                 step_kernel: str | None = "auto"):
         self.step_kernel = step_kernel
         self.update(x, adj, entry)
 
@@ -252,7 +252,7 @@ class TieredBackend(_StagedRerankMixin):
     _UNSET = object()
 
     def __init__(self, index, rerank: bool = True, slow_tier=None,
-                 step_kernel: str | None = None):
+                 step_kernel: str | None = "auto"):
         self.do_rerank = rerank
         self.slow_tier = None
         self.step_kernel = step_kernel
@@ -422,7 +422,7 @@ class OutOfCoreBackend(_StagedRerankMixin):
 
     def __init__(self, codes, codebook, entry, slow_tier, *,
                  io_groups: int = 2, io_depth: int = 32,
-                 step_kernel: str | None = None):
+                 step_kernel: str | None = "auto"):
         self.io_groups = io_groups
         self.io_depth = io_depth
         self.step_kernel = step_kernel
@@ -586,7 +586,7 @@ class DistributedBackend:
                  k: int, query_chunk: int = 128, use_pq: bool = True,
                  beam_budget=None, budget_buckets: int | None = None,
                  shard_ok=None, shard_laws=None, merge: str = "hierarchical",
-                 step_kernel: str | None = None):
+                 step_kernel: str | None = "auto"):
         from repro.distributed import sharded_search as ss
 
         self.mesh = mesh
@@ -808,8 +808,10 @@ class SearchEngine:
     ``step_kernel`` ("reference" | "pallas" | "auto") selects the walk's hop
     implementation on the backend (``backend.set_step_kernel``): the
     reference hop chain or the fused Pallas beam step
-    (:mod:`repro.kernels.beam_step`) — bit-identical results either way
-    (the engine-parity kernel axis asserts it per backend and variant).
+    (:mod:`repro.kernels.beam_step`) — bit-identical results either way in
+    interpret mode (the engine-parity kernel axis asserts it per backend
+    and variant).  Backends default to "auto": the fused step on a TPU,
+    the reference hop elsewhere.
 
     ``search`` serves one batch, unpipelined.  ``search_batches`` serves a
     stream with double buffering: batch i+1's admission + probe are

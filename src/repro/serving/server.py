@@ -432,6 +432,7 @@ class _Dispatch:
     done: bool = False
     partial: BatchResult | None = None   # deadline hedge, computed once
     partial_failed: bool = False
+    partial_error: str = ""              # repr of the hedge's exception
 
 
 # ----------------------------------------------------------------- front door
@@ -484,6 +485,7 @@ class FrontDoor:
         self.submitted = 0
         self.admitted = 0
         self.dispatches = 0
+        self.partial_errors = 0              # deadline hedges that raised
         self.max_queued_lanes = 0
         self.max_open_lanes = 0
 
@@ -664,8 +666,12 @@ class FrontDoor:
             return None
         try:
             disp.partial = engine.partial_result(disp.flight)
-        except Exception:
+        except Exception as e:
+            # Counted and named, never swallowed: the request still times
+            # out, and stats()["partial_errors"] tells the operator why.
             disp.partial_failed = True
+            disp.partial_error = repr(e)
+            self.partial_errors += 1
             return None
         return disp.partial
 
@@ -690,8 +696,10 @@ class FrontDoor:
                     row = disp.requests.index(req)
                     self._complete_row(req, res, row, PARTIAL, now)
                 else:
-                    self._complete(req, TIMEOUT, now,
-                                   note="deadline expired in flight")
+                    note = "deadline expired in flight"
+                    if disp.partial_error:
+                        note += f"; partial failed: {disp.partial_error}"
+                    self._complete(req, TIMEOUT, now, note=note)
                 if all(r.future.done() for r in disp.requests):
                     # A wedged dispatch never reports done; once every lane
                     # is hedged the batch is no longer tracked as open.
@@ -766,6 +774,7 @@ class FrontDoor:
                 "open_lanes": self._open,
                 "max_queued_lanes": self.max_queued_lanes,
                 "max_open_lanes": self.max_open_lanes,
+                "partial_errors": self.partial_errors,
                 **{s: self.counts[s]
                    for s in (OK, PARTIAL, TIMEOUT, SHED, ERROR)},
                 "per_class": {n: dict(c)

@@ -412,7 +412,7 @@ def scenario_cells_lower():
                         ("mcgi-gist1m", "serve")]:
         cell = cells_mod.build_cell(arch, shape, mesh, smoke=True)
         compiled = cell.lower().compile()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         results[f"{arch}/{shape}"] = cost.get("flops", 0) > 0
     print(json.dumps(results))
 

@@ -113,3 +113,23 @@ def test_search_stats_io_accounting(built):
     evals = np.asarray(stats.dist_evals)
     assert (hops > 0).all() and (hops <= 50).all()
     assert (evals <= hops * idx.degree_cap).all()
+
+
+@pytest.mark.parametrize("seed,cap", [(0, 4), (1, 16)])
+def test_reverse_pairs_device_matches_host(seed, cap):
+    """The build step's on-device reverse-edge grouping yields the host
+    grouping's destinations (ascending) and candidate rows, in order, with
+    duplicates, INVALID slots and over-cap groups in the input."""
+    rng = np.random.default_rng(seed)
+    n, b, r = 50, 16, 8
+    node_ids = rng.permutation(n)[:b].astype(np.int32)
+    rows = rng.integers(0, n, (b, r)).astype(np.int32)
+    rows[rng.random((b, r)) < 0.25] = build.INVALID
+    want_d, want_c = build._reverse_pairs(node_ids, rows, cap)
+    dest, cand, count = build._reverse_pairs_device(
+        jnp.asarray(node_ids), jnp.asarray(rows), cap, n)
+    count = int(count)
+    assert count == want_d.size
+    np.testing.assert_array_equal(np.asarray(dest)[:count], want_d)
+    np.testing.assert_array_equal(np.asarray(cand)[:count], want_c)
+    assert (np.asarray(cand)[count:] == build.INVALID).all()

@@ -191,21 +191,22 @@ def test_resolve_impl_policy(monkeypatch):
 
 
 def test_ops_beam_step_request_routing(monkeypatch):
-    """``request="pallas"`` upgrades the CPU fallback to interpret mode —
-    never the oracle — while ``request="auto"`` takes the resolved impl."""
+    """The fused walk the step-kernel layer requests is never the oracle:
+    compiled on TPU, interpret mode on the CPU and under the env switch."""
     from repro.kernels import ops
 
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     calls = []
     monkeypatch.setattr(
-        ops._beam, "beam_step",
+        ops._beam, "beam_walk",
         lambda *a, **kw: calls.append(("kernel", kw["interpret"])))
     monkeypatch.setattr(
         ops._ref, "beam_step_ref", lambda *a, **kw: calls.append(("oracle",)))
     args = (None,) * 6
-    ops.beam_step(*args, kind="exact", request="pallas")
-    ops.beam_step(*args, kind="exact", request="auto")
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    ops.beam_walk(*args, kind="exact")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ops.beam_walk(*args, kind="exact")
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    ops.beam_step(*args, kind="exact", request="auto")
-    assert calls == [("kernel", True), ("oracle",), ("kernel", True)]
+    ops.beam_walk(*args, kind="exact")
+    assert calls == [("kernel", True), ("kernel", False), ("kernel", True)]

@@ -280,6 +280,63 @@ def test_dispatch_error_surfaces_as_error_status():
     assert door.stats()["open_lanes"] == 0
 
 
+class PartialFailEngine(FakeEngine):
+    """Offers deadline hedges whose partial computation raises."""
+
+    supports_partial = True
+
+    def partial_result(self, flight):
+        raise RuntimeError("injected partial failure")
+
+
+def test_failed_partial_is_counted_and_named():
+    """A deadline hedge that raises is never swallowed: the lane times out
+    with the exception in its note and stats() counts it."""
+    door, clock, _ = fake_door(eng=PartialFailEngine(), deadline_s=1.0,
+                               max_lanes=2, service_time=math.inf)
+    futs = [door.submit(np.float64([i, 0.0])) for i in range(2)]
+    clock.advance(1.0)
+    for f in futs:
+        res = f.result(timeout=0)
+        assert res.status == server.TIMEOUT
+        assert "injected partial failure" in res.note
+    assert door.stats()["partial_errors"] == 1      # one hedge per dispatch
+
+
+def test_serve_launcher_exit_check_on_failing_backend():
+    """The serving launcher's exit check: a failing backend's error
+    statuses and failed hedges fail the run, a healthy run and shed/timeout
+    outcomes do not, and so does any out-of-filter result."""
+    from repro.launch.serve import run_failures
+
+    failing, clock, _ = fake_door(eng=FakeEngine(fail_finish=True),
+                                  max_lanes=2)
+    for i in range(3):
+        failing.submit(np.float64([i, 0.0]))
+    server.drain_virtual(failing, clock)
+    reasons = run_failures(failing.stats())
+    assert len(reasons) == 1 and "3 front-door request(s)" in reasons[0]
+
+    hedged, clock, _ = fake_door(eng=PartialFailEngine(), deadline_s=1.0,
+                                 max_lanes=2, service_time=math.inf)
+    hedged.submit(np.float64([0.0, 0.0]))
+    hedged.submit(np.float64([1.0, 0.0]))
+    clock.advance(1.0)
+    assert run_failures(hedged.stats()) == ["1 deadline hedge(s) raised"]
+
+    healthy, clock, _ = fake_door(max_lanes=2, max_queue=2,
+                                  service_time=math.inf, probe_time=math.inf,
+                                  deadline_s=1.0)
+    for i in range(4):                    # two admitted (timeout), two shed
+        healthy.submit(np.float64([i, 0.0]))
+    clock.advance(1.0)
+    st = healthy.stats()
+    assert st["shed"] == 2 and st["timeout"] == 2
+    assert run_failures(st) == []
+    assert run_failures(st, out_of_filter=0) == []
+    assert run_failures(out_of_filter=3) == ["3 result(s) outside their filter"]
+
+
 # ----------------------------------------------------- shutdown / lifecycle
 
 
