@@ -82,11 +82,28 @@ simply holds one engine per class (sharing one backend — jit caches are
 keyed on config + shapes, so classes don't trample each other), each with
 its own calibrated (lam, l_min)
 (:func:`repro.core.calibrate.calibrate_budget_law_per_class`).
+
+Every pipeline stage runs inside a host span
+(``jax.profiler.TraceAnnotation``, free unless a profiler is running) that
+carries the batch's sequence number as its ``batch`` stat, so a profile
+lines up each device gap with the stage the host was in:
+
+* ``engine.dispatch`` — admission + probe dispatch (or the monolithic
+  program);
+* ``engine.walk_prefetch`` / ``engine.prefetch`` — the storage backends'
+  read-ahead stages;
+* ``engine.schedule`` — the bucket stage, split into ``engine.schedule.sync``
+  (granted budgets to the host), ``engine.schedule.plan`` (bucket family
+  and partition) and ``engine.schedule.launch`` (lane gathers + continue
+  dispatches; stats ``buckets``, ``lanes`` real, ``padded_lanes``);
+* ``engine.gather`` — collection, with ``engine.gather.sync`` around the
+  wait for the continue outputs.
 """
 from __future__ import annotations
 
 import copy
 import dataclasses
+import itertools
 import threading
 from typing import Any, Callable, Iterable, Iterator
 
@@ -98,6 +115,7 @@ from repro.core import search as search_mod
 from repro.serving import pipeline as pipe
 
 Array = jax.Array
+_span = jax.profiler.TraceAnnotation   # host span; see the module docstring
 
 
 @dataclasses.dataclass
@@ -772,6 +790,7 @@ class _InFlight:
     """
 
     queries: Any
+    batch: int                 # engine sequence number: the spans' ``batch``
     backend: Any = None
     excl: Any = None           # packed filter words ((Q, nw) uint32) or None
     ctxs: Any = None
@@ -863,6 +882,7 @@ class SearchEngine:
         # extra compile shapes.
         self.pad_quantum = pad_quantum
         self.coalesce_lanes = coalesce_lanes
+        self._batches = itertools.count()
         self._close_lock = threading.Lock()
         self._closed = False
         backend_budget = getattr(backend, "beam_budget", None)
@@ -1120,28 +1140,31 @@ class SearchEngine:
         backend (shallow copy) so every later stage — including ones that
         run after an :meth:`update_backend` — sees one consistent index
         version."""
-        backend = copy.copy(self.backend)
-        excl = self._pack_filter(flt, int(np.asarray(queries).shape[0]))
-        if not self._staged():
-            if hasattr(backend, "dispatch"):
-                if excl is not None:
-                    raise NotImplementedError(
-                        "filtered search is not supported on the "
-                        "distributed backend (no global node-id view)")
-                handles = backend.dispatch(queries)
-            else:
-                q = jnp.asarray(queries)
-                handles = backend.fixed(
-                    q, beam_width=self.beam_width, max_hops=self.max_hops,
-                    k=self.k, excl=excl)
-            return _InFlight(queries=queries, backend=backend, excl=excl,
-                             handles=handles)
-        ctxs = backend.admit(queries)
-        probe_state, budgets, hop_limits, q_lid = backend.probe(
-            ctxs, self.budget_cfg, excl=excl)
-        return _InFlight(queries=queries, backend=backend, excl=excl,
-                         ctxs=ctxs, probe_state=probe_state,
-                         budgets=budgets, hop_limits=hop_limits, q_lid=q_lid)
+        batch = next(self._batches)
+        with _span("engine.dispatch", batch=batch):
+            backend = copy.copy(self.backend)
+            excl = self._pack_filter(flt, int(np.asarray(queries).shape[0]))
+            if not self._staged():
+                if hasattr(backend, "dispatch"):
+                    if excl is not None:
+                        raise NotImplementedError(
+                            "filtered search is not supported on the "
+                            "distributed backend (no global node-id view)")
+                    handles = backend.dispatch(queries)
+                else:
+                    q = jnp.asarray(queries)
+                    handles = backend.fixed(
+                        q, beam_width=self.beam_width, max_hops=self.max_hops,
+                        k=self.k, excl=excl)
+                return _InFlight(queries=queries, batch=batch,
+                                 backend=backend, excl=excl, handles=handles)
+            ctxs = backend.admit(queries)
+            probe_state, budgets, hop_limits, q_lid = backend.probe(
+                ctxs, self.budget_cfg, excl=excl)
+            return _InFlight(queries=queries, batch=batch, backend=backend,
+                             excl=excl, ctxs=ctxs, probe_state=probe_state,
+                             budgets=budgets, hop_limits=hop_limits,
+                             q_lid=q_lid)
 
     def _schedule(self, f: _InFlight) -> _InFlight:
         """Host-bucket stage: sync the granted budgets (the transfer the
@@ -1156,19 +1179,32 @@ class SearchEngine:
         """
         if not self._staged():
             return f
-        cfg = self.budget_cfg
-        f.budgets_np = np.asarray(f.budgets)
-        sched = f.backend.schedule_budgets(f.budgets_np)
-        f.ceilings = self._resolve_ceilings(sched, cfg)
-        cont = f.backend.continue_fn(cfg)
-        if f.ceilings is None or len(f.ceilings) <= 1:
-            f.dispatched = cont(f.probe_state, f.ctxs, f.budgets,
-                                f.hop_limits)
-        else:
-            f.dispatched = pipe.dispatch_bucketed_continue(
-                cont, f.probe_state, f.ctxs, f.budgets, f.hop_limits,
-                f.ceilings, budgets_np=sched,
-                quantum=self.pad_quantum)
+        with _span("engine.schedule", batch=f.batch):
+            cfg = self.budget_cfg
+            with _span("engine.schedule.sync", batch=f.batch):
+                f.budgets_np = np.asarray(f.budgets)
+                sched = f.backend.schedule_budgets(f.budgets_np)
+            with _span("engine.schedule.plan", batch=f.batch):
+                f.ceilings = self._resolve_ceilings(sched, cfg)
+                buckets = (None if f.ceilings is None or len(f.ceilings) <= 1
+                           else pipe.partition_by_bucket(
+                               sched, f.ceilings, self.pad_quantum))
+            nq = sched.shape[0]
+            if buckets is None:     # one program over the whole batch
+                n_buckets, padded = 1, nq
+            else:
+                n_buckets = len(buckets)
+                padded = sum(p.size for _, _, p in buckets)
+            with _span("engine.schedule.launch", batch=f.batch,
+                       buckets=n_buckets, lanes=nq, padded_lanes=padded):
+                cont = f.backend.continue_fn(cfg)
+                if buckets is None:
+                    f.dispatched = cont(f.probe_state, f.ctxs, f.budgets,
+                                        f.hop_limits)
+                else:
+                    f.dispatched = pipe.dispatch_bucketed_continue(
+                        cont, f.probe_state, f.ctxs, f.budgets, f.hop_limits,
+                        buckets)
         return f
 
     def _walk_prefetch(self, f: _InFlight) -> _InFlight:
@@ -1178,8 +1214,9 @@ class SearchEngine:
         tier's cache while other batches' device programs run.  Pure cache
         warm-up; results never depend on it."""
         if self._staged():
-            f.walk_prefetch = f.backend.prefetch_walk(
-                f.probe_state, f.budgets, f.hop_limits)
+            with _span("engine.walk_prefetch", batch=f.batch):
+                f.walk_prefetch = f.backend.prefetch_walk(
+                    f.probe_state, f.budgets, f.hop_limits)
         return f
 
     def _prefetch(self, f: _InFlight) -> _InFlight:
@@ -1190,8 +1227,9 @@ class SearchEngine:
         future one stage later.  Absent from the stage list unless the
         backend's slow tier is disk-backed."""
         if self._staged():
-            f.parts = self._continue_parts(f)
-            f.prefetch = f.backend.prefetch_rerank(f.parts)
+            with _span("engine.prefetch", batch=f.batch):
+                f.parts = self._continue_parts(f)
+                f.prefetch = f.backend.prefetch_rerank(f.parts)
         return f
 
     def _continue_parts(self, f: _InFlight) -> tuple:
@@ -1211,10 +1249,11 @@ class SearchEngine:
         (``backend.promotion_tick``, non-blocking; a no-op for backends
         without a frequency-aware tier): the tick digests the frequency
         the batch just recorded while the next batches' stages run."""
-        res = self._collect(f)
-        tick = getattr(self.backend, "promotion_tick", None)
-        if tick is not None:
-            tick()
+        with _span("engine.gather", batch=f.batch):
+            res = self._collect(f)
+            tick = getattr(self.backend, "promotion_tick", None)
+            if tick is not None:
+                tick()
         return res
 
     def _collect(self, f: _InFlight) -> BatchResult:
@@ -1226,7 +1265,8 @@ class SearchEngine:
                 ids=np.asarray(ids), d2=np.asarray(d2), stats=stats,
                 astats=astats,
                 extras=getattr(f.backend, "finish_extras", dict)())
-        parts = self._continue_parts(f)
+        with _span("engine.gather.sync", batch=f.batch):
+            parts = self._continue_parts(f)
         res = f.backend.finish(f.queries, parts, self.k, q_lid=f.q_lid,
                                budgets_np=f.budgets_np,
                                prefetch=f.prefetch)

@@ -213,22 +213,18 @@ def dispatch_bucketed_continue(
     ctxs,
     budgets,
     hop_limits,
-    ceilings: tuple[int, ...],
-    budgets_np: np.ndarray | None = None,
-    quantum: int = 8,
+    buckets: list[tuple[int, np.ndarray, np.ndarray]],
 ) -> list[tuple[np.ndarray, tuple]]:
-    """Dispatch half of the deferred discipline: partition the batch and
-    enqueue every bucket's continue program; nothing blocks.  Returns
+    """Dispatch half of the deferred discipline: enqueue the continue
+    program of every bucket of ``buckets`` (a :func:`partition_by_bucket`
+    partition, made by the caller's planning step); nothing blocks.  Returns
     [(members, device handles)] for :func:`gather_bucketed_continue` —
     the staged engine runs the two halves in different pipeline stages, so
     another batch's programs sit between dispatch and gather."""
-    if budgets_np is None:
-        budgets_np = np.asarray(budgets)
     dispatched = [
         (members, _dispatch_bucket(continue_fn, probe_state, ctxs, budgets,
                                    hop_limits, padded))
-        for _bi, members, padded in partition_by_bucket(budgets_np, ceilings,
-                                                        quantum)
+        for _bi, members, padded in buckets
     ]
     if not dispatched:   # zero-query batch — see bucketed_continue
         dispatched = [_zero_lane_bucket(continue_fn, probe_state, ctxs,
