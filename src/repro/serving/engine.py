@@ -89,13 +89,16 @@ carries the batch's sequence number as its ``batch`` stat, so a profile
 lines up each device gap with the stage the host was in:
 
 * ``engine.dispatch`` — admission + probe dispatch (or the monolithic
-  program);
+  program); stat ``warmed``, the lane counts whose compiled gather the
+  probe warmed (every padded lane count on the first batch of a new shape,
+  see :class:`repro.serving.pipeline.LaneGathers`; 0 after);
 * ``engine.walk_prefetch`` / ``engine.prefetch`` — the storage backends'
   read-ahead stages;
 * ``engine.schedule`` — the bucket stage, split into ``engine.schedule.sync``
   (granted budgets to the host), ``engine.schedule.plan`` (bucket family
-  and partition) and ``engine.schedule.launch`` (lane gathers + continue
-  dispatches; stats ``buckets``, ``lanes`` real, ``padded_lanes``);
+  and partition) and ``engine.schedule.launch`` (one compiled lane gather
+  + one continue dispatch per bucket; stats ``buckets``, ``lanes`` real,
+  ``padded_lanes``);
 * ``engine.gather`` — collection, with ``engine.gather.sync`` around the
   wait for the continue outputs.
 """
@@ -202,6 +205,7 @@ class ExactBackend(_StagedRerankMixin):
     def __init__(self, x: Array, adj: Array, entry: Array,
                  step_kernel: str | None = "auto"):
         self.step_kernel = step_kernel
+        self.lane_gathers = pipe.LaneGathers()
         self.update(x, adj, entry)
 
     def update(self, x: Array, adj: Array, entry: Array) -> None:
@@ -221,9 +225,9 @@ class ExactBackend(_StagedRerankMixin):
         return jnp.asarray(queries)
 
     def probe(self, ctxs, budget_cfg, excl=None):
-        return search_mod._probe_exact_jit(
+        return self.lane_gathers.warm(search_mod._probe_exact_jit(
             self.x, self.adj, ctxs, self.entry, budget_cfg,
-            step_kernel=self.step_kernel, excl=excl)
+            step_kernel=self.step_kernel, excl=excl), ctxs)
 
     def continue_fn(self, budget_cfg):
         import functools
@@ -274,6 +278,7 @@ class TieredBackend(_StagedRerankMixin):
         self.do_rerank = rerank
         self.slow_tier = None
         self.step_kernel = step_kernel
+        self.lane_gathers = pipe.LaneGathers()
         self.update(index, slow_tier=slow_tier)
 
     def set_step_kernel(self, step_kernel: str | None) -> None:
@@ -328,10 +333,10 @@ class TieredBackend(_StagedRerankMixin):
         return _query_luts(self.index, jnp.asarray(queries))
 
     def probe(self, ctxs, budget_cfg, excl=None):
-        return search_mod._probe_pq_jit(
+        return self.lane_gathers.warm(search_mod._probe_pq_jit(
             self.index.codes, self.index.graph.adj, ctxs,
             self.index.graph.entry, budget_cfg,
-            step_kernel=self.step_kernel, excl=excl)
+            step_kernel=self.step_kernel, excl=excl), ctxs)
 
     def continue_fn(self, budget_cfg):
         import functools
@@ -444,6 +449,7 @@ class OutOfCoreBackend(_StagedRerankMixin):
         self.io_groups = io_groups
         self.io_depth = io_depth
         self.step_kernel = step_kernel
+        self.lane_gathers = pipe.LaneGathers()
         self.slow_tier = None
         self.update(codes, codebook, entry, slow_tier=slow_tier)
 
@@ -499,10 +505,10 @@ class OutOfCoreBackend(_StagedRerankMixin):
     def probe(self, ctxs, budget_cfg, excl=None):
         from repro.index import disk as disk_mod
 
-        return disk_mod.ooc_probe(
+        return self.lane_gathers.warm(disk_mod.ooc_probe(
             self.codes, ctxs, self.entry, int(self.codes.shape[0]),
             budget_cfg, self.slow_tier, io_groups=self.io_groups,
-            excl=excl)
+            excl=excl), ctxs)
 
     def continue_fn(self, budget_cfg):
         from repro.index import disk as disk_mod
@@ -626,6 +632,7 @@ class DistributedBackend:
             query_chunk=query_chunk, use_pq=use_pq,
             budget_buckets=budget_buckets, merge=merge)
         self.step_kernel = step_kernel
+        self.lane_gathers = pipe.LaneGathers()
         # One more bucket costs one more *whole-mesh* program (n_shards
         # shard walks + merge collectives + the checkpoint-state gather),
         # not one more single-host kernel launch: scale the scheduler's
@@ -736,9 +743,9 @@ class DistributedBackend:
                 f"to equal the backend's beam_budget; got {budget_cfg} vs "
                 f"{self.beam_budget}")
         a = self.arrays
-        return self._probe_step(a["adj"], a["codes"], a["vectors"],
-                                a["centroids"], ctxs, a["entries"],
-                                *self._laws())
+        return self.lane_gathers.warm(self._probe_step(
+            a["adj"], a["codes"], a["vectors"], a["centroids"], ctxs,
+            a["entries"], *self._laws()), ctxs)
 
     def continue_fn(self, budget_cfg):
         a = self.arrays
@@ -862,7 +869,8 @@ class SearchEngine:
     def __init__(self, backend, budget_cfg=None, *, k: int = 10,
                  beam_width: int = 48, max_hops: int = 2048,
                  num_buckets: int | str | None = "auto",
-                 pad_quantum: int = 4, coalesce_lanes: int | None = None,
+                 pad_quantum: int = pipe.LANE_QUANTUM,
+                 coalesce_lanes: int | None = None,
                  step_kernel: str | None = None):
         self.backend = backend
         if step_kernel is not None:
@@ -879,7 +887,9 @@ class SearchEngine:
         # default is finer than the historical 8: with tight DP-chosen
         # ceilings and serving-size micro-batches, quantum-4 padding was
         # measured (CPU) to cut padded-lane inflation enough to beat the
-        # extra compile shapes.
+        # extra compile shapes.  The backends' lane gathers are warmed on
+        # the ``pipe.LANE_QUANTUM`` grid: a quantum off that grid compiles
+        # a lane count's gather when a batch first needs it.
         self.pad_quantum = pad_quantum
         self.coalesce_lanes = coalesce_lanes
         self._batches = itertools.count()
@@ -1141,7 +1151,7 @@ class SearchEngine:
         run after an :meth:`update_backend` — sees one consistent index
         version."""
         batch = next(self._batches)
-        with _span("engine.dispatch", batch=batch):
+        with _span("engine.dispatch", batch=batch) as span:
             backend = copy.copy(self.backend)
             excl = self._pack_filter(flt, int(np.asarray(queries).shape[0]))
             if not self._staged():
@@ -1159,8 +1169,10 @@ class SearchEngine:
                 return _InFlight(queries=queries, batch=batch,
                                  backend=backend, excl=excl, handles=handles)
             ctxs = backend.admit(queries)
+            warmed = backend.lane_gathers.warmed
             probe_state, budgets, hop_limits, q_lid = backend.probe(
                 ctxs, self.budget_cfg, excl=excl)
+            span.set_metadata(warmed=backend.lane_gathers.warmed - warmed)
             return _InFlight(queries=queries, batch=batch, backend=backend,
                              excl=excl, ctxs=ctxs, probe_state=probe_state,
                              budgets=budgets, hop_limits=hop_limits,

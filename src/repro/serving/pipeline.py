@@ -8,7 +8,15 @@ device-side programs themselves (probe / continue / rerank) stay in
 :mod:`repro.core.search` — they are pure kernels; this file is the scheduler
 that drives them.
 
-Two gather disciplines are provided:
+A bucket's lanes are cut out of the batch's probe state and per-lane
+inputs by one compiled gather program (:func:`gather_lanes`), so a bucket
+costs two dispatches on a single-host backend: that gather and the
+continue program.  A staged backend passes every probe state through its
+:class:`LaneGathers`, which compiles the gather for every padded lane count
+on first sight of a batch shape, so no later batch of that shape compiles
+anything.
+
+Two collection disciplines are provided:
 
 * eager (:func:`bucketed_continue`) — each bucket's results are pulled to
   the host before the next bucket is dispatched.  This is the historical
@@ -36,6 +44,10 @@ fetch) ever waits on it.
 """
 from __future__ import annotations
 
+import concurrent.futures
+import os
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,6 +60,10 @@ from repro.core import search as search_mod
 # enough that splitting a bucket which saves fewer than ~a padded row of hops
 # is rejected (measured CPU-only break-even is a few hundred lane-hops).
 BUCKET_LAUNCH_COST_HOPS = 512
+# Bucket lane counts are padded to multiples of this (the engine's
+# ``pad_quantum`` default, and the grid :class:`LaneGathers` compiles).
+LANE_QUANTUM = 4
+WARM_THREADS = max(1, min(8, os.cpu_count() or 1))
 
 
 def pad_bucket_size(n: int, quantum: int = 8) -> int:
@@ -249,11 +265,95 @@ def gather_bucketed_continue(q: int, dispatched):
     return out
 
 
+@jax.jit
+def _gather_lanes(leaves: list, sel):
+    """Lanes ``sel`` (axis 0) of every array of ``leaves``, as one compiled
+    program: the same ``a[sel]`` per leaf that an eager gather runs."""
+    return [a[sel] for a in leaves]
+
+
+def gather_lanes(probe_state, ctxs, budgets, hop_limits, padded: np.ndarray):
+    """(sub_state, sub_ctxs, sub_budgets, sub_hop_limits) of the lanes
+    ``padded`` (a :func:`partition_by_bucket` member list).
+
+    One dispatch of the compiled gather per placement of the device
+    leaves — one in all on a single-host backend; the distributed backend's
+    uncommitted queries and its mesh-sharded state are two.  Grouping by
+    placement keeps every output what eager ``a[sel]`` gives, bit for bit
+    and in shape, dtype, weak type, sharding and commitment, so the
+    continue programs' jit caches see the same signatures either way.  A
+    host (numpy) leaf, such as the queries the core ``num_buckets=`` entry
+    points pass through, is gathered on the host, as eager ``a[sel]`` did."""
+    sel = jnp.asarray(padded)
+    leaves, tree = jax.tree_util.tree_flatten(
+        (probe_state, ctxs, budgets, hop_limits))
+    out = [None] * len(leaves)
+    groups: dict = {}
+    for i, a in enumerate(leaves):
+        if isinstance(a, jax.Array):
+            groups.setdefault((a.committed, frozenset(a.sharding.device_set)),
+                              []).append(i)
+        else:
+            out[i] = a[padded]
+    for idx in groups.values():
+        for i, g in zip(idx, _gather_lanes([leaves[i] for i in idx], sel)):
+            out[i] = g
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+class LaneGathers:
+    """Warms the compiled lane gather of one backend's probe states.
+
+    A staged backend holds one and passes every probe state it returns
+    through :meth:`warm`.  The first probe state of a new signature (every
+    gathered leaf's shape, dtype, weak type, sharding and commitment)
+    compiles the gather for every padded lane count a bucket of it can
+    have, ``LANE_QUANTUM``, ``2 * LANE_QUANTUM``, .., the padded batch, by
+    running it on ``arange(p) % Q`` from ``WARM_THREADS`` threads (XLA
+    releases the GIL while it compiles).  So whatever probes a new batch
+    shape first, a serving stream or a warm-up, compiles the family, and
+    no later batch of that shape stalls on a compile.  ``warmed`` counts
+    the lane counts warmed so far.
+    """
+
+    def __init__(self):
+        self.warmed = 0
+        self._seen: set = set()
+        self._lock = threading.Lock()
+
+    def warm(self, probe_out, ctxs):
+        """Return the probe's ``(probe_state, budgets, hop_limits, q_lid)``
+        unchanged, after compiling its gather family on first sight."""
+        probe_state, budgets, hop_limits = probe_out[:3]
+        leaves, tree = jax.tree_util.tree_flatten(
+            (probe_state, ctxs, budgets, hop_limits))
+        sig = (tree, tuple(
+            (a.shape, a.dtype) + tuple(getattr(a, k, None) for k in (
+                "weak_type", "sharding", "committed"))
+            for a in leaves))
+        q = ctxs.shape[0]
+        with self._lock:
+            if q == 0 or sig in self._seen:
+                return probe_out
+            self._seen.add(sig)
+        counts = range(LANE_QUANTUM, pad_bucket_size(q, LANE_QUANTUM) + 1,
+                       LANE_QUANTUM)
+
+        def compile_one(p: int) -> None:   # outputs dropped at once
+            gather_lanes(probe_state, ctxs, budgets, hop_limits,
+                         np.arange(p) % q)
+
+        with concurrent.futures.ThreadPoolExecutor(WARM_THREADS) as pool:
+            list(pool.map(compile_one, counts))
+        with self._lock:
+            self.warmed += len(counts)
+        return probe_out
+
+
 def _dispatch_bucket(continue_fn, probe_state, ctxs, budgets, hop_limits,
                      padded: np.ndarray):
-    sel = jnp.asarray(padded)
-    sub_state = jax.tree_util.tree_map(lambda a: a[sel], probe_state)
-    return continue_fn(sub_state, ctxs[sel], budgets[sel], hop_limits[sel])
+    return continue_fn(*gather_lanes(probe_state, ctxs, budgets, hop_limits,
+                                     padded))
 
 
 def _zero_lane_bucket(continue_fn, probe_state, ctxs, budgets, hop_limits):

@@ -262,6 +262,44 @@ def scenario_staged_engine():
         r0.ids.shape == (0, 10) and r0.d2.shape == (0, 10)
         and np.asarray(r0.extras["shard_ids"]).shape == (0, 10))
 
+    # The compiled bucket gather on the sharded probe state: the continue
+    # receives what the eager per-leaf ``a[sel]`` gave it — the same
+    # values, shapes, dtypes, weak types, shardings and commitment (so its
+    # jit cache gains no entry) — and returns the same results.
+    from repro.serving import pipeline as pipe
+
+    gb = staged.backend
+    ctxs = gb.admit(q)
+    state, budgets, hop_limits, _ = gb.probe(ctxs, budget)
+    # Four buckets of 13, 13, 13 and 9 lanes, padded to 16, 16, 16, 12.
+    parts = pipe.partition_by_bucket(
+        np.minimum(np.arange(q.shape[0]) // 13, 3), (0, 1, 2, 3), 4)
+    cont = gb.continue_fn(budget)
+    same_inputs, eager_out = True, []
+    for _bi, _members, padded in parts:
+        sel = jnp.asarray(padded)
+        eager = (jax.tree_util.tree_map(lambda a: a[sel], state), ctxs[sel],
+                 budgets[sel], hop_limits[sel])
+        got = pipe.gather_lanes(state, ctxs, budgets, hop_limits, padded)
+        for e, g in zip(jax.tree_util.tree_leaves(eager),
+                        jax.tree_util.tree_leaves(got)):
+            same_inputs &= (
+                e.shape == g.shape and e.dtype == g.dtype
+                and e.weak_type == g.weak_type and e.sharding == g.sharding
+                and e.committed == g.committed
+                and bool((np.asarray(e) == np.asarray(g)).all()))
+        eager_out.append([np.asarray(h) for h in cont(*eager)])
+    compiled = gb._continue_step._cache_size()
+    same_outputs = all(
+        all((np.asarray(h) == w).all()
+            for h, w in zip(cont(*pipe.gather_lanes(
+                state, ctxs, budgets, hop_limits, padded)), want))
+        for (_bi, _m, padded), want in zip(parts, eager_out))
+    out["gather_eq_eager"] = bool(same_inputs and same_outputs
+                                  and len(parts) > 1)
+    out["gather_no_continue_recompile"] = (
+        gb._continue_step._cache_size() == compiled)
+
     # Permutation invariance (pinned center).
     perm = np.random.default_rng(7).permutation(q.shape[0])
     inv = np.argsort(perm)

@@ -91,6 +91,17 @@ def test_staged_engine_parity():
 
 
 @pytest.mark.slow
+def test_staged_bucket_gather_matches_eager():
+    """The compiled lane gather of a bucket over the sharded probe state:
+    the continue program receives what the eager per-leaf gather gave it
+    (values, shapes, dtypes, weak types, shardings) and compiles nothing
+    new for it."""
+    r = _staged()
+    for key in ("gather_eq_eager", "gather_no_continue_recompile"):
+        assert r[key], (key, r)
+
+
+@pytest.mark.slow
 def test_front_door_over_distributed_backend():
     """The async front door serving the staged distributed backend under a
     virtual clock: served lanes bit-identical to direct dispatch, a shard

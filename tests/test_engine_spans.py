@@ -2,7 +2,9 @@
 
 Every stage of a staged batch runs inside a ``jax.profiler.TraceAnnotation``
 that carries the batch's sequence number (``batch``); the launch span also
-carries the partition's counts.  A profile of ``search_batches``, ``search``
+carries the partition's counts, and the dispatch span ``warmed``, the lane
+counts whose gather the probe compiled (all of them on the first batch of a
+shape, none after).  A profile of ``search_batches``, ``search``
 and ``begin`` + ``finish_from`` must hold one span of each stage per batch,
 each sub-stage inside its stage, with ``lanes`` / ``padded_lanes`` equal to
 the bucket partition — and the results must not depend on whether a
@@ -88,6 +90,7 @@ def test_engine_spans_per_batch_nested_with_partition_counts(
     by_batch = _engine_spans(tmp_path)
     assert sorted(by_batch) == list(range(len(traced)))
     multi = 0
+    warmed_sizes: set = set()
     for bid, res in zip(sorted(by_batch), traced):
         spans = {}
         for name, s, e, stats in by_batch[bid]:
@@ -100,8 +103,16 @@ def test_engine_spans_per_batch_nested_with_partition_counts(
                 ps, pe, _ = spans[parent]
                 assert ps <= s <= e <= pe, (bid, name)
 
-        stats = spans["engine.schedule.launch"][2]
         nq = res.ids.shape[0]
+        # The first probe of a shape compiles the lane gather for every
+        # padded lane count; every later batch of that shape compiles none.
+        q = pipe.LANE_QUANTUM
+        assert spans["engine.dispatch"][2]["warmed"] == (
+            0 if nq in warmed_sizes
+            else len(range(q, pipe.pad_bucket_size(nq, q) + 1, q))), bid
+        warmed_sizes.add(nq)
+
+        stats = spans["engine.schedule.launch"][2]
         assert stats["lanes"] == nq
         if len(res.ceilings) <= 1:
             assert stats["buckets"] == 1 and stats["padded_lanes"] == nq
