@@ -9,7 +9,7 @@ calibrating it against an operational recall target instead of hand-tuning
 per dataset.
 
 Monotonicity makes this a bisection, not a grid search: with the budget
-center at the batch-mean LID, ``lam = 0`` serves every query at the
+center at the batch's LID center, ``lam = 0`` serves every query at the
 geometric-mean budget, and raising ``lam`` spreads budgets apart —
 below-average-LID queries shrink toward ``l_min`` (that's where the I/O
 savings come from) while above-average ones grow toward ``l_max``. Measured
@@ -398,7 +398,8 @@ def shard_exact_recall_evals(
     ``vectors``/``adj`` are the concatenated shard-major arrays (shard s owns
     rows [s*per, (s+1)*per) with shard-local adjacency ids — the layout
     ``make_distributed_search`` requires, *before* device_put); ``entries``
-    the per-shard local medoids. Shard recall is measured against the
+    the (n_shards, E) entry candidates, each query starting from its
+    nearest as in serving. Shard recall is measured against the
     shard's own brute-force top-k: the budget law governs the shard-local
     walk, and the global merge sits outside it. The held-out sample is drawn
     once per shard from the same seed, so every shard calibrates against the
@@ -409,7 +410,8 @@ def shard_exact_recall_evals(
     def make_shard_eval(s: int) -> Callable:
         x_s = vectors[s * per:(s + 1) * per]
         adj_s = adj[s * per:(s + 1) * per]
-        entry_s = jnp.asarray(entries)[s]
+        entry_s = search_mod.nearest_entries(
+            jnp.asarray(entries)[s], jnp.asarray(x_s), jnp.asarray(queries))
         _, gt_s = distance_mod.brute_force_topk(
             jnp.asarray(queries), jnp.asarray(x_s), k=k)
 
@@ -430,8 +432,7 @@ def _candidate_grants(cfg: search_mod.AdaptiveBeamBudget, q_lid):
     lam / hop_factor / center never enter a static argument here)."""
     from repro.core import mapping as mapping_mod
 
-    center = (jnp.float32(cfg.center) if cfg.center is not None
-              else jnp.mean(q_lid))
+    center = None if cfg.center is None else jnp.float32(cfg.center)
     budgets = mapping_mod.adaptive_beam_budget(
         q_lid, cfg.lam, cfg.l_min, cfg.l_max, mu=center)
     return budgets, search_mod._bucket_hop_limits(cfg, budgets, None)
@@ -461,10 +462,13 @@ def exact_recall_eval(
     (l_min/l_max/probe_hops/lid_k), never on lam or hop_factor, so it runs
     *once*, lazily, at the first evaluation; each candidate then re-runs only
     the continue phase with its own (traced) budgets and hop limits — the
-    whole bisection shares two compiled programs.
+    whole bisection shares two compiled programs.  ``entry`` is one node
+    for every query, or one per row of ``queries``.
     """
     sel = holdout_sample(queries.shape[0], sample, seed)
     q_s, gt_s = queries[sel], gt_ids[sel][:, :k]
+    if jnp.ndim(entry):
+        entry = jnp.asarray(entry)[sel]
     probe = {}
 
     def eval_recall(cfg: search_mod.AdaptiveBeamBudget) -> float:

@@ -33,13 +33,16 @@ class IvfIndex:
 
 
 def kmeans(
-    x: Array, k: int, iters: int = 10, key: Array | None = None, chunk: int = 65536
+    x: Array, k: int, iters: int = 10, key: Array | None = None, chunk: int = 65536,
+    init: Array | None = None,
 ) -> Array:
-    """Batched Lloyd's algorithm (shared with the PQ codebook trainer)."""
-    key = jax.random.PRNGKey(0) if key is None else key
+    """Batched Lloyd's algorithm (shared with the PQ codebook trainer), from
+    ``init`` ((k, d) centroids) or k distinct rows drawn with ``key``."""
     n = x.shape[0]
-    init = jax.random.choice(key, n, shape=(k,), replace=False)
-    centroids = x[init]
+    if init is None:
+        key = jax.random.PRNGKey(0) if key is None else key
+        init = x[jax.random.choice(key, n, shape=(k,), replace=False)]
+    centroids = init
 
     @jax.jit
     def assign(c, xs):
@@ -55,6 +58,26 @@ def kmeans(
         empty = counts == 0
         centroids = jnp.where(empty[:, None], centroids, new)
     return centroids
+
+
+def kmeans_pp(x: Array, k: int, first: Array, key: Array) -> Array:
+    """k-means++ seeding: (k, d) rows of ``x``, the first ``x[first]``,
+    each next drawn with probability proportional to its squared distance
+    from the nearest row drawn so far, so a dense region far from the
+    others is all but sure to get one."""
+
+    def d2_to(c):
+        diff = x - c[None, :]
+        return jnp.sum(diff * diff, axis=1)
+
+    def draw(i, st):
+        cent, dmin, key = st
+        key, sub = jax.random.split(key)
+        j = jax.random.categorical(sub, jnp.log(jnp.maximum(dmin, 1e-30)))
+        return (cent.at[i].set(x[j]), jnp.minimum(dmin, d2_to(x[j])), key)
+
+    cent = jnp.zeros((k, x.shape[1]), x.dtype).at[0].set(x[first])
+    return jax.lax.fori_loop(1, k, draw, (cent, d2_to(x[first]), key))[0]
 
 
 def build_ivf(x: Array, nlist: int = 256, iters: int = 10, seed: int = 0) -> IvfIndex:

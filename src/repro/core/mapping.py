@@ -65,6 +65,31 @@ def constant_alpha(n: int, alpha: float) -> Array:
     return jnp.full((n,), alpha, dtype=jnp.float32)
 
 
+OUTLIER_MADS = 5.0
+
+
+def budget_center(lid: Array) -> Array:
+    """The batch's LID center for the budget law: the mean of the estimates
+    within ``OUTLIER_MADS`` robust standard deviations (1.4826 * MAD) of
+    the median.
+
+    A probe beam that is still far from the query's cluster holds nearly
+    equidistant candidates, and Eq. 5's MLE then reads an LID in the
+    hundreds (or inf); a plain batch mean lets a few such estimates push
+    every other query of the batch down to ``l_min``.  The center skips
+    them, so it equals the plain mean on a batch without such estimates.
+    Skipping rather than capping them at the band's edge (their share of
+    the batch would still set the others' grants) or centering on the
+    median (which shifts every grant of a skewed but clean batch) keeps
+    the center where the batch's ordinary queries put it.
+    """
+    med = jnp.median(lid)
+    scale = 1.4826 * jnp.median(jnp.abs(lid - med))
+    keep = jnp.abs(lid - med) <= OUTLIER_MADS * scale
+    return (jnp.sum(jnp.where(keep, lid, 0.0))
+            / jnp.sum(keep).astype(lid.dtype))
+
+
 def adaptive_beam_budget(
     lid: Array,
     lam: float | Array,
@@ -74,8 +99,10 @@ def adaptive_beam_budget(
 ) -> Array:
     """Prop. 4.2's iso-recall budget  L(q) = C * exp(lambda * LID(q)).
 
-    Normalised so a query of average complexity gets the geometric mean of
-    [l_min, l_max]; clipped to the operational range. Integer-valued.
+    Normalised so a query of the batch's typical complexity
+    (:func:`budget_center`, unless ``mu`` pins the center) gets the
+    geometric mean of [l_min, l_max]; clipped to the operational range.
+    Integer-valued.
 
     ``lam`` and ``l_min`` may be traced scalars: the distributed serving path
     threads *per-shard* calibrated budget laws through as runtime arrays
@@ -87,7 +114,7 @@ def adaptive_beam_budget(
     compensates in the topology); on TPU a *grouped* adaptive beam is feasible
     because queries are batched — see ``repro/core/search.py`` early-exit.
     """
-    center = jnp.mean(lid) if mu is None else mu
+    center = budget_center(lid) if mu is None else mu
     l_mid = jnp.sqrt(jnp.asarray(l_min, jnp.float32)
                      * jnp.asarray(l_max, jnp.float32))
     budget = l_mid * jnp.exp(lam * (lid - center))

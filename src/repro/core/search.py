@@ -83,8 +83,9 @@ class AdaptiveBeamBudget:
       lid_k:       neighbourhood size for the online LID estimate.
       probe_hops:  hops spent in the probe phase before budgets are set.
       hop_factor:  per-query hop limit = probe_hops + hop_factor * budget.
-      center:      LID normalisation center; None -> batch mean (self
-        normalising — robust to the ADC-vs-exact distance scale difference).
+      center:      LID normalisation center; None -> the batch's own
+        center, :func:`repro.core.mapping.budget_center` (self normalising —
+        robust to the ADC-vs-exact distance scale difference).
     """
 
     l_min: int
@@ -432,6 +433,22 @@ def _search_one(
     return beam_ids, beam_d, SearchStats(hops=hops, dist_evals=evals)
 
 
+def _init_states(ctxs, entry, eval_dists: DistEval, n: int,
+                 beam_width: int, excl: Array | None = None):
+    """:func:`_init_state` for every lane of a batch; ``entry`` is one node
+    for every lane, or one per lane ((Q,) — the per-query entry points of
+    the distributed probe)."""
+    e_axis = 0 if jnp.ndim(entry) else None
+    if excl is None:
+        return jax.vmap(
+            lambda c, e: _init_state(c, e, eval_dists, n, beam_width),
+            in_axes=(0, e_axis))(ctxs, entry)
+    return jax.vmap(
+        lambda c, e, x: _init_state(c, e, eval_dists, n, beam_width,
+                                    excl_words=x),
+        in_axes=(0, e_axis, 0))(ctxs, entry, excl)
+
+
 def fixed_search_batch(
     ctxs: Array,
     adj: Array,
@@ -455,13 +472,7 @@ def fixed_search_batch(
     pre-seed) and the exit beam is scrubbed of the forced entry seed.
     """
     kernel = resolve_step_kernel(step_kernel)
-    if excl is None:
-        states = jax.vmap(
-            lambda c: _init_state(c, entry, eval_dists, n, beam_width))(ctxs)
-    else:
-        states = jax.vmap(
-            lambda c, e: _init_state(c, entry, eval_dists, n, beam_width,
-                                     excl_words=e))(ctxs, excl)
+    states = _init_states(ctxs, entry, eval_dists, n, beam_width, excl)
     hop_limits = jnp.full((ctxs.shape[0],), jnp.int32(max_hops))
     beam_ids, beam_d, _, _, hops, evals = kernel.run_batch(
         states, ctxs, adj, eval_dists, beam_width, hop_limits)
@@ -646,8 +657,8 @@ def grant_budgets(
     d_pool = jnp.where(p_ids == INVALID, jnp.inf, p_d)
     q_lid = lid_mod.online_lid(d_pool, k=min(budget_cfg.lid_k,
                                              budget_cfg.l_max))
-    center = (jnp.float32(budget_cfg.center)
-              if budget_cfg.center is not None else jnp.mean(q_lid))
+    center = (None if budget_cfg.center is None
+              else jnp.float32(budget_cfg.center))
     budgets = mapping_mod.adaptive_beam_budget(
         q_lid, lam_, l_min_, budget_cfg.l_max, mu=center)
     hop_limits = _bucket_hop_limits(budget_cfg, budgets, max_hops)
@@ -698,13 +709,7 @@ def adaptive_probe_batch(
     l_min_ = budget_cfg.l_min if l_min is None else l_min
 
     kernel = resolve_step_kernel(step_kernel)
-    if excl is None:
-        states = jax.vmap(
-            lambda c: _init_state(c, entry, eval_dists, n, l_max))(ctxs)
-    else:
-        states = jax.vmap(
-            lambda c, e: _init_state(c, entry, eval_dists, n, l_max,
-                                     excl_words=e))(ctxs, excl)
+    states = _init_states(ctxs, entry, eval_dists, n, l_max, excl)
     nq = ctxs.shape[0]
     probe_state = kernel.run_batch(
         states, ctxs, adj, eval_dists, l_max,
@@ -1111,3 +1116,14 @@ def medoid(x: Array) -> Array:
     c = jnp.mean(x, axis=0, keepdims=True)
     diff = x - c
     return jnp.argmin(jnp.sum(diff * diff, axis=-1)).astype(jnp.int32)
+
+
+def nearest_entries(candidates: Array, x: Array, queries: Array) -> Array:
+    """(Q,) entry node per query: the row of ``candidates`` ((E,) node ids
+    of ``x``; one node is a set of one) nearest each query by exact
+    distance, so a walk starts inside the query's own region instead of at
+    one medoid many hops from it."""
+    candidates = jnp.atleast_1d(candidates).astype(jnp.int32)
+    cand = x[candidates].astype(jnp.float32)                   # (E, d)
+    diff = queries.astype(jnp.float32)[:, None, :] - cand[None, :, :]
+    return candidates[jnp.argmin(jnp.sum(diff * diff, axis=-1), axis=1)]

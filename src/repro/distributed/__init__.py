@@ -5,6 +5,7 @@ from repro.distributed.sharded_search import (  # noqa: F401
     make_distributed_continue,
     make_distributed_probe,
     make_distributed_search,
+    shard_entry_points,
     shard_medoids,
     sharded_index_specs,
 )

@@ -54,6 +54,7 @@ them). ``l_max`` stays global: it is the physical beam shape.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -124,7 +125,9 @@ def sharded_index_specs(
         ),
         queries=jax.ShapeDtypeStruct((n_queries, d), jnp.float32, sharding=repl),
         shard_ok=jax.ShapeDtypeStruct((n_shards,), jnp.bool_, sharding=row),
-        entries=jax.ShapeDtypeStruct((n_shards,), jnp.int32, sharding=row),
+        entries=jax.ShapeDtypeStruct(
+            (n_shards, entry_count(n_pad // n_shards)), jnp.int32,
+            sharding=NamedSharding(mesh, P(axes, None))),
         **laws,
     )
 
@@ -268,8 +271,10 @@ def _local_search(
     """Per-shard search over the local sub-graph. Returns (d2, local_ids)
     each (Q, k).
 
-    ``entry`` is the shard's own entry point (its local medoid, computed at
-    index-build time and threaded through :class:`ShardedIndexSpecs`). With
+    ``entry`` is the shard's (E,) entry candidates (computed at index-build
+    time by :func:`shard_entry_points` and threaded through
+    :class:`ShardedIndexSpecs`); each query starts from the nearest
+    (:func:`repro.core.search.nearest_entries`). With
     ``beam_budget`` set, the shard runs the adaptive engine: each query's
     budget is computed *on this shard* from its local probe beam (shard
     geometry differs, so budgets legitimately differ per shard) and the
@@ -290,22 +295,22 @@ def _local_search(
     mechanism for shards that are down entirely.
     """
     n_local = adj.shape[0]
-    entry = entry.astype(jnp.int32)
     eval_dists = _shard_eval(codes, vectors, use_pq)
     ctxs = _shard_ctxs(centroids, queries, use_pq)
 
     def chunk_fn(args):
         ctx_chunk, q_chunk = args
+        entry_q = search_mod.nearest_entries(entry, vectors, q_chunk)
         if beam_budget is not None:
             # max_hops still caps every per-query hop limit: enabling
             # adaptivity must not silently exceed the operator's I/O SLO.
             beam_ids, beam_d, _, _ = search_mod.adaptive_search_batch(
-                ctx_chunk, adj, entry, eval_dists, n_local, beam_budget,
+                ctx_chunk, adj, entry_q, eval_dists, n_local, beam_budget,
                 max_hops=max_hops, bucket_ceilings=bucket_ceilings,
                 lam=lam, l_min=l_min, step_kernel=step_kernel)
         else:
             beam_ids, beam_d, _ = search_mod.fixed_search_batch(
-                ctx_chunk, adj, entry, eval_dists, n_local, beam_width,
+                ctx_chunk, adj, entry_q, eval_dists, n_local, beam_width,
                 max_hops, step_kernel=step_kernel)
         d2, ids = _local_rerank(beam_ids, vectors, q_chunk, k)
         return d2, ids
@@ -338,8 +343,9 @@ def make_distributed_search(
          [, shard_lam, shard_l_min])
       -> (d2 (Q, k), shard_id (Q, k), local_id (Q, k))
 
-    ``entries`` is the (n_shards,) array of per-shard entry points (local
-    medoids), sharded one per device like ``shard_ok``.
+    ``entries`` is the (n_shards, E) array of entry candidates per shard
+    (:func:`shard_entry_points`), one row per device; each query starts
+    from its nearest.
 
     Global ids are returned as (shard, local_id) pairs — billion-scale ids
     exceed int32 when flattened.
@@ -404,7 +410,7 @@ def make_distributed_search(
             P(),            # centroids
             P(),            # queries
             P(axes),        # shard_ok (1 flag per shard)
-            P(axes),        # entries  (1 entry point per shard)
+            P(axes, None),  # entries (candidates per shard)
         ]
         if per_shard_laws:
             specs_in += [P(axes), P(axes)]  # shard_lam, shard_l_min
@@ -448,7 +454,7 @@ def make_distributed_probe(
     used.
 
     Queries are probed in ``query_chunk`` groups under ``lax.map`` exactly
-    like the monolithic step (so batch-mean LID centering sees the same
+    like the monolithic step (so batch LID centering sees the same
     chunks); a batch not divisible by the chunk runs as one chunk — staged
     serving accepts ragged *micro*-batches the monolithic step would reject
     (bounded at max(4 x query_chunk, 512) lanes, past which the single
@@ -465,7 +471,6 @@ def make_distributed_probe(
         def shard_fn(adj_l, codes_l, vectors_l, centroids_l, queries_l,
                      entry_l, *laws_l):
             n_local = adj_l.shape[0]
-            entry = entry_l[0].astype(jnp.int32)
             eval_dists = _shard_eval(codes_l, vectors_l, use_pq)
             ctxs = _shard_ctxs(centroids_l, queries_l, use_pq)
             lam_l = laws_l[0][0] if per_shard_laws else None
@@ -481,7 +486,10 @@ def make_distributed_probe(
                 f"query_chunk={query_chunk} and too large to probe as one "
                 f"chunk; align bulk batches to the chunk grid")
 
-            def chunk_fn(ctx_chunk):
+            def chunk_fn(args):
+                ctx_chunk, q_chunk = args
+                entry = search_mod.nearest_entries(entry_l[0], vectors_l,
+                                                   q_chunk)
                 st, budgets, hop_limits, q_lid = search_mod.adaptive_probe_batch(
                     ctx_chunk, adj_l, entry, eval_dists, n_local, budget_cfg,
                     max_hops=max_hops, lam=lam_l, l_min=l_min_l,
@@ -494,7 +502,9 @@ def make_distributed_probe(
                 return st + (budgets, hop_limits, q_lid)
 
             ctx_chunks = ctxs.reshape((nq // chunk, chunk) + ctxs.shape[1:])
-            outs = jax.lax.map(chunk_fn, ctx_chunks)
+            q_chunks = queries_l.reshape(
+                (nq // chunk, chunk) + queries_l.shape[1:])
+            outs = jax.lax.map(chunk_fn, (ctx_chunks, q_chunks))
             outs = jax.tree_util.tree_map(
                 lambda a: a.reshape((nq,) + a.shape[2:]), outs)
             b_ids, b_d, b_exp, visited, hops, evals, budgets, hop_limits, \
@@ -512,7 +522,7 @@ def make_distributed_probe(
             P(axes, None),  # vectors
             P(),            # centroids
             P(),            # queries
-            P(axes),        # entries
+            P(axes, None),  # entries
         ]
         if per_shard_laws:
             specs_in += [P(axes), P(axes)]
@@ -543,7 +553,8 @@ def make_distributed_continue(
     cont(adj, codes, vectors, centroids, probe_state, queries, budgets,
          hop_limits, shard_ok)
       -> (d2 (q, k), shard_id (q, k), local_id (q, k),
-          hops (q,), dist_evals (q,))
+          hops (q,), dist_evals (q,),
+          shard_hops (q, n_shards), shard_capped (q, n_shards))
 
     Resumes the checkpointed shard walks (warm beam + visited set, no
     repeated hops) for *any query subset* of a probe's batch — the host
@@ -555,7 +566,11 @@ def make_distributed_continue(
 
     ``hops``/``dist_evals`` are the per-query totals summed over *live*
     shards (the monolithic step reports no counters; the staged path is
-    strictly more observable).
+    strictly more observable).  ``shard_hops`` are the same hops per
+    shard (0 on a shard masked out, so they sum to ``hops``), and
+    ``shard_capped`` marks a live shard's walk that stopped at its hop
+    limit rather than converging: shard skew and the budget law's work
+    are read from them.
     """
     axes = _shard_axes(mesh)
 
@@ -571,9 +586,12 @@ def make_distributed_continue(
                 budgets_l[:, 0], hop_limits_l[:, 0], step_kernel=step_kernel)
             d2, ids = _local_rerank(beam_ids, vectors_l, queries_l, k)
             d2, sid, lid = _hedged_merge(d2, ids, ok_l, mesh, axes, merge)
-            live_hops = jax.lax.psum(jnp.where(ok_l[0], hops, 0), axes)
+            shard_hops = jnp.where(ok_l[0], hops, 0)
+            live_hops = jax.lax.psum(shard_hops, axes)
             live_evals = jax.lax.psum(jnp.where(ok_l[0], evals, 0), axes)
-            return d2, sid, lid, live_hops, live_evals
+            capped = ok_l[0] & (hops >= hop_limits_l[:, 0])
+            return (d2, sid, lid, live_hops, live_evals, shard_hops[:, None],
+                    capped[:, None])
 
         state_specs = ((P(None, axes, None),) * 4
                        + (P(None, axes),) * 2
@@ -591,7 +609,8 @@ def make_distributed_continue(
         )
         return compat.shard_map(
             shard_fn, mesh=mesh, in_specs=specs_in,
-            out_specs=(P(), P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P(), P(), P(None, axes),
+                       P(None, axes)),
         )(adj, codes, vectors, centroids, state, queries, budgets,
           hop_limits, shard_ok)
 
@@ -609,6 +628,48 @@ def shard_medoids(vectors: Array, n_shards: int) -> Array:
     return jax.vmap(search_mod.medoid)(blocks)
 
 
+ENTRY_CAP = 128
+
+
+def entry_count(rows_per_shard: int) -> int:
+    """Entry candidates per shard: sqrt(rows), at most ``ENTRY_CAP`` (the
+    per-query choice scans every candidate), at least the medoid alone."""
+    return max(1, min(ENTRY_CAP, math.isqrt(rows_per_shard)))
+
+
+def shard_entry_points(vectors: Array, n_shards: int, seed: int = 0) -> Array:
+    """(n_shards, E) entry candidates per shard, shard-local ids,
+    E = :func:`entry_count` of the rows per shard: the shard's medoid, then
+    the row nearest each centroid of an (E - 1)-means clustering of the
+    shard's rows, seeded by k-means++ from the medoid so every dense region
+    of the shard gets a candidate whatever ``seed`` is.
+
+    With one medoid per shard a query whose region lies far from it spends
+    the probe's few hops travelling, and its probe beam then reads an LID
+    in the hundreds; starting from the nearest candidate puts the probe in
+    the query's own region."""
+    from repro.core import ivf
+
+    per = vectors.shape[0] // n_shards
+    n_cent = entry_count(per) - 1
+    medoids = shard_medoids(vectors, n_shards)
+    if n_cent == 0:
+        return medoids[:, None]
+    out = []
+    for s in range(n_shards):
+        xs = jnp.asarray(vectors[s * per:(s + 1) * per], jnp.float32)
+        init = ivf.kmeans_pp(xs, n_cent, medoids[s],
+                             key=jax.random.PRNGKey(seed + s))
+        cent = ivf.kmeans(xs, n_cent, iters=5, init=init)
+        # |row|^2 - 2 c.row: the centroid's own |c|^2 ranks no row.
+        d = (jnp.sum(xs * xs, axis=1)[None, :]
+             - 2.0 * jnp.matmul(cent, xs.T,
+                                precision=jax.lax.Precision.HIGHEST))
+        out.append(jnp.concatenate(
+            [medoids[s][None], jnp.argmin(d, axis=1).astype(jnp.int32)]))
+    return jnp.stack(out)
+
+
 def build_sharded_arrays(
     x: Array,
     mesh,
@@ -623,9 +684,9 @@ def build_sharded_arrays(
 
     One locally built sub-graph per shard (shard-local ids, static
     ``alpha``), PQ codebook/codes over the full collection, per-shard entry
-    medoids — all ``device_put`` with the shardings
-    :func:`make_distributed_search` requires. ``x`` is truncated to a
-    multiple of the shard count. Returns (arrays dict, rows_per_shard).
+    candidates (:func:`shard_entry_points`) — all ``device_put`` with the
+    shardings :func:`make_distributed_search` requires. ``x`` is truncated
+    to a multiple of the shard count. Returns (arrays dict, rows_per_shard).
 
     Each shard's sub-graph is built on the mesh device that will own it,
     all shards concurrently (one host thread per device; the builds are
@@ -658,17 +719,27 @@ def build_sharded_arrays(
         adj = np.concatenate(list(pool.map(
             build_shard, range(n_shards), mesh.devices.flat)))
     book = train_pq(x, m=m_pq, iters=pq_iters, seed=seed)
+    arrays = place_sharded_arrays(
+        mesh, adj=adj, codes=pq_encode(x, book), vectors=x,
+        centroids=book.centroids,
+        entries=shard_entry_points(x, n_shards, seed=seed))
+    return arrays, per
+
+
+def place_sharded_arrays(mesh, *, adj, codes, vectors, centroids,
+                         entries) -> dict:
+    """Lay shard-major index arrays (host or device, e.g. a saved build
+    of :func:`build_sharded_arrays`) out on ``mesh`` with the shardings
+    :func:`make_distributed_search` requires."""
     axes = _shard_axes(mesh)
     row = NamedSharding(mesh, P(axes, None))
-    flag = NamedSharding(mesh, P(axes))
-    arrays = {
+    return {
         "adj": jax.device_put(adj, row),
-        "codes": jax.device_put(pq_encode(x, book), row),
-        "vectors": jax.device_put(x, row),
-        "centroids": jax.device_put(book.centroids, NamedSharding(mesh, P())),
-        "entries": jax.device_put(shard_medoids(x, n_shards), flag),
+        "codes": jax.device_put(codes, row),
+        "vectors": jax.device_put(vectors, row),
+        "centroids": jax.device_put(centroids, NamedSharding(mesh, P())),
+        "entries": jax.device_put(entries, row),
     }
-    return arrays, per
 
 
 def distributed_search(mesh, index_arrays, queries, shard_ok=None,
@@ -676,9 +747,9 @@ def distributed_search(mesh, index_arrays, queries, shard_ok=None,
     """Convenience eager entry (tests, examples): index_arrays is a dict with
     adj/codes/vectors/centroids (optionally entries) laid out shard-major.
 
-    When ``entries`` is absent the per-shard medoids are recomputed here on
-    *every call* — an O(N·D) scan. Production callers should compute them
-    once at index-build time and put them in the dict. ``shard_laws`` is an
+    When ``entries`` is absent the per-shard entry candidates are recomputed
+    here on *every call* — a k-means over each shard. Production callers
+    should compute them once at index-build time and put them in the dict. ``shard_laws`` is an
     optional (lam (S,), l_min (S,)) pair of per-shard budget-law arrays.
     """
     step = make_distributed_search(
@@ -688,7 +759,7 @@ def distributed_search(mesh, index_arrays, queries, shard_ok=None,
         shard_ok = jnp.ones((n_shards,), jnp.bool_)
     entries = index_arrays.get("entries")
     if entries is None:
-        entries = shard_medoids(index_arrays["vectors"], n_shards)
+        entries = shard_entry_points(index_arrays["vectors"], n_shards)
     laws = ()
     if shard_laws is not None:
         laws = (jnp.asarray(shard_laws[0], jnp.float32),

@@ -618,7 +618,7 @@ class DistributedBackend:
         n_shards = mesh.devices.size
         self.rows_per_shard = arrays["vectors"].shape[0] // n_shards
         if "entries" not in self.arrays:
-            self.arrays["entries"] = ss.shard_medoids(
+            self.arrays["entries"] = ss.shard_entry_points(
                 arrays["vectors"], n_shards)
         self.shard_ok = (shard_ok if shard_ok is not None
                          else jnp.ones((n_shards,), jnp.bool_))
@@ -771,7 +771,10 @@ class DistributedBackend:
 
     def finish(self, queries, parts, k: int, *, q_lid,
                budgets_np, prefetch=None) -> BatchResult:
-        d2, shard_ids, local_ids, hops, evals = parts
+        """``extras`` adds the per (query, shard) counters of the continue
+        program: ``shard_hops``, ``shard_budget`` (the granted budget) and
+        ``shard_capped`` (the walk stopped at its hop limit)."""
+        d2, shard_ids, local_ids, hops, evals, shard_hops, capped = parts
         sid = shard_ids.astype(np.int64)
         lid = local_ids.astype(np.int64)
         return BatchResult(
@@ -779,7 +782,9 @@ class DistributedBackend:
             stats=search_mod.SearchStats(hops=hops, dist_evals=evals),
             astats=search_mod.AdaptiveStats(q_lid=np.asarray(q_lid),
                                             budget=budgets_np),
-            extras={"shard_ids": sid, "local_ids": lid})
+            extras={"shard_ids": sid, "local_ids": lid,
+                    "shard_hops": shard_hops, "shard_budget": budgets_np,
+                    "shard_capped": capped})
 
 
 @dataclasses.dataclass
@@ -856,7 +861,7 @@ class SearchEngine:
     back so every input batch still yields its own :class:`BatchResult`
     (per-query order preserved) — the cross-batch admission coalescing a hot
     upstream batcher needs.  Coalescing is result-transparent per query
-    under a pinned LID center; with batch-mean centering, budgets depend on
+    under a pinned LID center; with batch centering, budgets depend on
     which queries share a dispatch (the reducer's property, as with any
     batching choice).
 

@@ -82,7 +82,7 @@ Lane padding: ``QoSClass(lane_quantum=)`` pads each dispatch to a lane-count
 grid (repeating the first lane; padded rows are dropped on completion) so a
 front door under ragged traffic compiles a bounded family of batch shapes —
 the same discipline as the pipeline's bucket ``pad_quantum``.  Under a
-pinned LID center padding is result-transparent per lane; with batch-mean
+pinned LID center padding is result-transparent per lane; with batch
 centering, budgets depend on dispatch composition (the reducer's property,
 as with any batching choice).
 """

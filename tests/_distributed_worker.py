@@ -360,6 +360,76 @@ def scenario_staged_engine():
     print(json.dumps(out))
 
 
+def scenario_sharded_serving():
+    """The staged mesh walk served through ``SearchEngine`` on a (2, 2) mesh
+    of four devices, under the engine's own budget center, against a plain
+    float32 brute force over the whole set: the distance of every served
+    global id, recall@10, and the per-shard counters against the totals."""
+    from repro import serving
+    from repro.core import build
+    from repro.core.search import AdaptiveBeamBudget
+    from repro.data.synthetic import gaussian_subspace_clusters
+    from repro.distributed import sharded_search as ss
+
+    mesh = compat.make_mesh((2, 2), ("data", "model"),
+                            devices=jax.devices()[:4])
+    pts = np.asarray(gaussian_subspace_clusters(
+        jax.random.PRNGKey(7), 2048 + 96, 32, d_intrinsic=6, n_clusters=8))
+    perm = np.random.default_rng(7).permutation(pts.shape[0])
+    x, q = pts[perm[:2048]], pts[perm[2048:]]
+    cfg = build.BuildConfig(degree=16, beam_width=32, iters=1, batch=128,
+                            max_hops=64)
+    arrays, per = ss.build_sharded_arrays(jnp.asarray(x), mesh,
+                                          build_cfg=cfg, m_pq=8, seed=7)
+    budget = AdaptiveBeamBudget(l_min=8, l_max=32, lam=0.25, probe_hops=4)
+    backend = serving.DistributedBackend(
+        mesh, arrays, beam_width=32, max_hops=4 + 4 * 32, k=10,
+        query_chunk=32, beam_budget=budget)
+    engine = serving.SearchEngine(backend, budget, k=10)
+    results = list(engine.search_batches([q[:32], q[32:64], q[64:]]))
+    ids = np.concatenate([r.ids for r in results])
+    d2 = np.concatenate([r.d2 for r in results])
+    # Plain float32 brute force, on the host.
+    diff = x[None, :, :] - q[:, None, :]
+    full = np.einsum("qnd,qnd->qn", diff, diff, dtype=np.float32)
+    gt = np.argsort(full, axis=1, kind="stable")[:, :10]
+    served = np.take_along_axis(full, ids, axis=1)
+    hits = (gt[:, :, None] == ids[:, None, :]).any(axis=2)
+    counters = []
+    for r in results:
+        e = r.extras
+        counters.append(bool(
+            e["shard_hops"].shape == (r.ids.shape[0], 4)
+            and (e["shard_hops"].sum(axis=1) == r.stats.hops).all()
+            and (e["shard_budget"] == r.astats.budget).all()
+            and e["shard_capped"].dtype == np.bool_
+            and (e["shard_hops"][e["shard_capped"]]
+                 >= 4 + 4 * 8).all()))
+    # Each query starts every shard's walk from the nearest of that shard's
+    # entry candidates (the medoid first); the staged walk is still the
+    # monolithic step's, bit for bit.
+    mono = serving.SearchEngine(serving.DistributedBackend(
+        mesh, arrays, beam_width=32, max_hops=4 + 4 * 32, k=10,
+        query_chunk=32, beam_budget=budget), None, k=10).search(q[:32])
+    entries = np.asarray(arrays["entries"])
+    print(json.dumps({
+        "entries_shape": list(entries.shape),
+        "medoid_first": bool((entries[:, 0] == np.asarray(
+            ss.shard_medoids(jnp.asarray(x), 4))).all()),
+        "staged_eq_mono": bool((results[0].ids == mono.ids).all()
+                               and (results[0].d2 == mono.d2).all()),
+        "rows_per_shard": int(per),
+        "ids_in_range": bool(((ids >= 0) & (ids < x.shape[0])).all()),
+        "d2_max_rel_err": float(np.max(np.abs(d2 - served)
+                                       / np.maximum(served, 1e-12))),
+        "recall_at_10": float(hits.sum(axis=1).mean() / 10),
+        "counters_sum_to_totals": all(counters),
+        "budget_in_range": bool(all(
+            ((r.astats.budget >= 8) & (r.astats.budget <= 32)).all()
+            for r in results)),
+    }))
+
+
 def scenario_front_door():
     """The serving front door over the staged distributed backend: served
     lanes bit-identical to a direct engine dispatch, a mid-stream shard
@@ -473,5 +543,7 @@ if __name__ == "__main__":
         scenario_staged_engine()
     elif scen == "front_door":
         scenario_front_door()
+    elif scen == "sharded_serving":
+        scenario_sharded_serving()
     else:
         raise SystemExit(f"unknown scenario {scen}")

@@ -162,3 +162,27 @@ def test_shard_medoids_matches_per_block_medoid():
     for s in range(4):
         block = x[s * 16:(s + 1) * 16]
         assert int(ents[s]) == int(search.medoid(block))
+
+
+def test_shard_entry_points_cover_every_cluster():
+    """Each shard's entry candidates (sqrt(rows) of them, its medoid first)
+    put one in every tight cluster of the shard, whatever the seed: the
+    k-means++ seeding reaches a far cluster that uniformly drawn initial
+    rows would often miss."""
+    rng = np.random.default_rng(5)
+    n_shards, clusters, size, d = 4, 16, 25, 8
+    shards, labels = [], []
+    for _ in range(n_shards):
+        centers = rng.normal(size=(clusters, d)) * 50.0
+        lab = np.repeat(np.arange(clusters), size)
+        shards.append(centers[lab] + rng.normal(size=(lab.size, d)) * 0.1)
+        labels.append(lab)
+    x = jnp.asarray(np.concatenate(shards), jnp.float32)
+    for seed in (0, 1, 2):
+        ents = np.asarray(ss.shard_entry_points(x, n_shards, seed=seed))
+        assert ents.shape == (n_shards, ss.entry_count(clusters * size))
+        assert ents.shape[1] == 20
+        np.testing.assert_array_equal(ents[:, 0],
+                                      np.asarray(ss.shard_medoids(x, 4)))
+        for s in range(n_shards):
+            assert set(labels[s][ents[s, 1:]]) == set(range(clusters))

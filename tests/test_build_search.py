@@ -133,3 +133,23 @@ def test_reverse_pairs_device_matches_host(seed, cap):
     np.testing.assert_array_equal(np.asarray(dest)[:count], want_d)
     np.testing.assert_array_equal(np.asarray(cand)[:count], want_c)
     assert (np.asarray(cand)[count:] == build.INVALID).all()
+
+
+def test_per_query_entries_match_a_shared_entry(built):
+    """A batch walked from one entry per query, all equal to the index's
+    entry, is the shared-entry walk bit for bit; a query started from its
+    own nearest neighbour keeps it first in its beam."""
+    x, q, gt_i, idx = built
+    cfg = search.AdaptiveBeamBudget(l_min=8, l_max=48, lam=0.25)
+    ev = search._exact_eval(x)
+    n = x.shape[0]
+    shared = search.adaptive_probe_batch(q, idx.adj, idx.entry, ev, n, cfg)
+    each = search.adaptive_probe_batch(
+        q, idx.adj, jnp.full((q.shape[0],), idx.entry, jnp.int32), ev, n,
+        cfg)
+    for a, b in zip(jax.tree_util.tree_leaves(shared),
+                    jax.tree_util.tree_leaves(each)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    own = gt_i[:, 0].astype(jnp.int32)
+    ids, d2, _ = search.fixed_search_batch(q, idx.adj, own, ev, n, 48, 16)
+    np.testing.assert_array_equal(np.asarray(ids)[:, 0], np.asarray(own))

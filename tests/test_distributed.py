@@ -126,3 +126,21 @@ def test_staged_fault_injection_mid_stream():
     for key in ("fault_no_dead_results", "fault_best_so_far_finite",
                 "fault_recall_bounded", "fault_no_recompile"):
         assert r[key], (key, r)
+
+
+@pytest.mark.slow
+def test_sharded_serving_matches_brute_force():
+    """The staged mesh walk served by ``SearchEngine`` on four devices
+    against a plain float32 brute force over the whole set: every served
+    distance is the true distance of its global id (shard * rows + local
+    id), recall@10 meets its floor, and the per (query, shard) counters
+    the continue program returns sum to the per-query totals.  Each shard
+    holds sqrt(rows) entry candidates, its medoid first, and the staged walk
+    from them equals the monolithic step's."""
+    r = _run("sharded_serving")
+    assert r["rows_per_shard"] == 512 and r["ids_in_range"], r
+    assert r["d2_max_rel_err"] <= 1e-5, r
+    assert r["recall_at_10"] >= 0.9, r
+    assert r["counters_sum_to_totals"] and r["budget_in_range"], r
+    assert r["entries_shape"] == [4, 22] and r["medoid_first"], r
+    assert r["staged_eq_mono"], r

@@ -139,3 +139,64 @@ def test_fused_walk_program_compiles(one_chip, monkeypatch):
                     s((N, r), jnp.int32), s((N, M_PQ), jnp.uint8),
                     s((Q,), jnp.int32), s((Q,), jnp.int32))
     assert "tpu_custom_call" in text and "while" in text
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(one_chip):
+    """A (2, 2) mesh over the described v5e:2x2 (``one_chip`` loads the TPU
+    library and turns the compile cache off first)."""
+    from jax.experimental import topologies
+
+    from repro import compat
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return compat.make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+
+
+def test_sharded_probe_and_continue_compile(mesh_2x2, monkeypatch):
+    """The staged mesh walk of the sharded deployment at its size — four
+    shards of 100,000 rows, R = 32, PQ M = 16, 256 queries, 128 entry
+    candidates per shard — compiles for four v5e chips with the fused
+    kernel in both programs and the merge's all-gather in the continue."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.search import AdaptiveBeamBudget
+    from repro.distributed import sharded_search as ss
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "resolve_impl", lambda: "pallas")
+    mesh, axes = mesh_2x2, ("data", "model")
+    n, d, r, q, lanes = 400_000, 128, 32, 256, 64
+
+    def spec(shape, dtype, *parts):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*parts)))
+
+    index = (spec((n, r), jnp.int32, axes, None),
+             spec((n, M_PQ), jnp.uint8, axes, None),
+             spec((n, d), jnp.float32, axes, None),
+             spec((M_PQ, K_PQ, d // M_PQ), jnp.float32))
+    cfg = AdaptiveBeamBudget(l_min=8, l_max=L, lam=0.25)
+    probe = ss.make_distributed_probe(mesh, budget_cfg=cfg, max_hops=520,
+                                      query_chunk=128, step_kernel="auto")
+    p_args = index + (spec((q, d), jnp.float32),
+                      spec((4, ss.entry_count(n // 4)), jnp.int32, axes,
+                           None))
+    assert "tpu_custom_call" in _compile(probe, *p_args)
+    state = jax.eval_shape(jax.jit(probe), *p_args)[0]
+
+    def lanes_of(a):
+        per_shard = a.ndim >= 2 and a.shape[1] == 4
+        return spec((lanes,) + a.shape[1:], a.dtype,
+                    *((None, axes) + (None,) * (a.ndim - 2)
+                      if per_shard else ()))
+
+    cont = ss.make_distributed_continue(mesh, budget_cfg=cfg, k=K_TOP,
+                                        step_kernel="auto")
+    text = _compile(cont, *index, jax.tree_util.tree_map(lanes_of, state),
+                    spec((lanes, d), jnp.float32),
+                    spec((lanes, 4), jnp.int32, None, axes),
+                    spec((lanes, 4), jnp.int32, None, axes),
+                    spec((4,), jnp.bool_, axes))
+    assert "tpu_custom_call" in text and "all-gather" in text
